@@ -21,6 +21,8 @@ MODULES = {
 
 
 def main() -> None:
+    from repro.launch import compile_cache
+    compile_cache.enable()
     want = sys.argv[1:] or list(MODULES)
     print("name,us_per_call,derived")
     failed = []

@@ -324,12 +324,36 @@ class ModelAdapter:
                             cost=vu.cost, latency=vu.latency)).add(u2)
 
     def _real_generate(self, model: PoolModel, prompt: str, out_tokens: int) -> str:
+        """One request through the same Scheduler as the batched path, so a
+        request answered alone decodes exactly as it would in a batch."""
+        texts, _ = self._real_generate_batch(model, [prompt], [out_tokens])
+        return texts[0]
+
+    @staticmethod
+    def _scheduler(model: PoolModel, n_slots: int):
+        """The serving Scheduler for an engine-backed model: the paged KV
+        cache (and its paged attention kernels) for attention-only
+        families, speculative when the model carries a draft engine; the
+        dense cache for recurrent families, which have no paged layout."""
+        from repro.models.transformer import PAGED_FAMILIES
+        from repro.serving.scheduler import Scheduler
+        if model.engine.cfg.family not in PAGED_FAMILIES:
+            return Scheduler(model.engine, n_slots=n_slots)
+        draft = None
+        if model.draft_engine is not None:
+            from repro.serving.engine import DraftEngine
+            draft = DraftEngine(model.draft_engine, n_slots=n_slots,
+                                max_len=model.engine.max_len)
+        return Scheduler(model.engine, n_slots=n_slots, paged=True,
+                         draft=draft, spec_k=model.spec_k)
+
+    @staticmethod
+    def _prompt_ids(model: PoolModel, prompt: str):
+        """The prompt's last tokens, leaving the engine room to decode."""
         import jax.numpy as jnp
-        ids = model.tokenizer.encode(prompt)[-64:]
-        toks = jnp.asarray([ids], jnp.int32)
-        gen = model.engine.generate(
-            toks, max_new=min(out_tokens, self.max_engine_tokens))
-        return model.tokenizer.decode(list(np.asarray(gen[0])))
+        ids = model.tokenizer.encode(prompt)
+        keep = min(64, model.engine.max_len - 1)
+        return jnp.asarray(ids[-keep:], jnp.int32)
 
     # -- streaming generation (the incremental token channel) ------------------
     def _stream_generate(self, model: PoolModel, prompt: str,
@@ -353,38 +377,35 @@ class ModelAdapter:
     def _stream_real_generate(self, model: PoolModel, prompt: str,
                               out_tokens: int, stream) -> Tuple[str, int]:
         """Step-wise engine decode through the streaming Scheduler —
-        configured exactly like the buffered batch path (paged +
-        speculative when the model carries a draft engine), so the emitted
-        token sequence is bit-exact with ``request()``'s text.  The text
-        delta per event is a prefix diff of the full decode (byte-level
-        tokenizers make per-token decode non-concatenative; the diff is
-        concat-safe by construction)."""
-        import jax.numpy as jnp
-        from repro.serving.scheduler import Request, Scheduler
-        ids = model.tokenizer.encode(prompt)[-64:]
+        configured exactly like the buffered batch path (``_scheduler``),
+        so the emitted token sequence is bit-exact with ``request()``'s
+        text.  The text delta per event is a prefix diff of the full decode
+        (byte-level tokenizers make per-token decode non-concatenative).  A
+        trailing U+FFFD may be a multi-byte character whose bytes are still
+        arriving, so it is held back until a later token settles it and the
+        deltas concatenate to exactly the buffered text."""
+        from repro.serving.scheduler import Request
         cap = min(out_tokens, self.max_engine_tokens)
-        if model.draft_engine is not None:
-            from repro.serving.engine import DraftEngine
-            draft = DraftEngine(model.draft_engine, n_slots=1,
-                                max_len=model.engine.max_len)
-            sched = Scheduler(model.engine, n_slots=1, paged=True,
-                              draft=draft, spec_k=model.spec_k)
-        else:
-            sched = Scheduler(model.engine, n_slots=1)
-        sched.submit(Request(rid=0, user="__stream__",
-                             prompt=jnp.asarray(ids, jnp.int32), max_new=cap))
+        ids = self._prompt_ids(model, prompt)
+        sched = self._scheduler(model, n_slots=1)
+        sched.submit(Request(rid=0, user="__stream__", prompt=ids,
+                             max_new=cap))
         emitted: List[int] = []
-        text = ""
+        text = full = ""
         cancelled = False
         while sched.pending() and not cancelled:
             for _req, new_toks, _done in sched.step_stream():
                 emitted.extend(new_toks)
                 full = model.tokenizer.decode(emitted)
-                delta, text = full[len(text):], full
+                stable = full.rstrip("\ufffd")
+                delta, text = stable[len(text):], stable
                 if not stream.emit(delta, token_ids=new_toks):
                     cancelled = True
                     sched.cancel(0)
                     break
+        if not cancelled and len(full) > len(text):
+            stream.emit(full[len(text):])
+            text = full
         if model.draft_engine is not None:
             self._note_spec(model.name, sched.spec_summary())
         return text, (len(emitted) if cancelled else out_tokens)
@@ -486,29 +507,20 @@ class ModelAdapter:
         budget tier weighs against the request in the slot-refill order.
         Returns ``(texts, realized)``: realized[i] is the decoded token
         count when row i's wall deadline truncated it, else None."""
-        import jax.numpy as jnp
-        from repro.serving.scheduler import Request, Scheduler
+        from repro.serving.scheduler import Request
         deadlines = deadlines or [None] * len(prompts)
         tiers = tiers or [0] * len(prompts)
         walls = walls or [None] * len(prompts)
-        n_slots = min(len(prompts), 8)
-        if model.draft_engine is not None:
-            from repro.serving.engine import DraftEngine
-            draft = DraftEngine(model.draft_engine, n_slots=n_slots,
-                                max_len=model.engine.max_len)
-            sched = Scheduler(model.engine, n_slots=n_slots, paged=True,
-                              draft=draft, spec_k=model.spec_k)
-        else:
-            sched = Scheduler(model.engine, n_slots=n_slots)
-        for i, (prompt, ot, dl, tier) in enumerate(
-                zip(prompts, out_tokens, deadlines, tiers)):
+        ids = [self._prompt_ids(model, prompt) for prompt in prompts]
+        sched = self._scheduler(model, n_slots=min(len(prompts), 8))
+        for i, (prompt_ids, ot, dl, tier) in enumerate(
+                zip(ids, out_tokens, deadlines, tiers)):
             if dl is not None:
                 affordable = int((dl - model.base_latency) /
                                  model.per_token_latency)
                 ot = max(1, min(ot, affordable))
-            ids = model.tokenizer.encode(prompt)[-64:]
             sched.submit(Request(rid=i, user=f"__batch__{i}",
-                                 prompt=jnp.asarray(ids, jnp.int32),
+                                 prompt=prompt_ids,
                                  max_new=min(ot, self.max_engine_tokens),
                                  deadline=dl, tier=tier))
         cancelled: set = set()

@@ -482,9 +482,11 @@ class PolicyCompiler:
                     single(f"mid,k={cfg_k}", mid, cfg_k),
                     single("cheapest,k=0", cheapest, 0)]
         if pref == Preference.BALANCED:
-            return [verify(f"verify,k={cfg_k}", cfg_k),
-                    single(f"mid,k={cfg_k}", mid, cfg_k),
-                    single("cheapest,k=0", cheapest, 0)]
+            # verification escalates M1 -> M2: a one-model pool has no M2
+            out = ([verify(f"verify,k={cfg_k}", cfg_k)]
+                   if len(pool.list()) >= 2 else [])
+            return out + [single(f"mid,k={cfg_k}", mid, cfg_k),
+                          single("cheapest,k=0", cheapest, 0)]
         if pref == Preference.LATENCY_FIRST:
             out = []
             if cons.allow_prefetch:

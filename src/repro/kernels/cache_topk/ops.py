@@ -21,18 +21,18 @@ def _shortlist_ref_jit(q, db, codes, shortlist, type_mask, threshold, k):
     return shortlist_topk_ref(q, db, codes, shortlist, type_mask, threshold, k)
 
 
-def similarity_topk(q, db, k: int, use_pallas: bool = False, interpret: bool = True):
+def similarity_topk(q, db, k: int, use_pallas: bool = False):
     """q: (Q, D); db: (N, D) -> (scores (Q,k), idx (Q,k)) as numpy arrays."""
     if use_pallas:
         s, i = similarity_topk_pallas(jax.numpy.asarray(q), jax.numpy.asarray(db),
-                                      k, interpret=interpret)
+                                      k)
     else:
         s, i = _ref_jit(jax.numpy.asarray(q), jax.numpy.asarray(db), k)
     return np.asarray(s), np.asarray(i)
 
 
 def shortlist_topk(q, db, codes, shortlist, type_mask, threshold, k: int,
-                   use_pallas: bool = False, interpret: bool = True):
+                   use_pallas: bool = False):
     """Masked shortlist scoring: gather + cosine + threshold + type-masked
     top-k fused in one pass (the IVF probe hot path).
 
@@ -45,7 +45,7 @@ def shortlist_topk(q, db, codes, shortlist, type_mask, threshold, k: int,
             jnp_.asarray(codes, jnp_.int32), jnp_.asarray(shortlist, jnp_.int32),
             jnp_.asarray(type_mask, jnp_.int32), jnp_.asarray(threshold, jnp_.float32))
     if use_pallas:
-        s, i = shortlist_topk_pallas(*args, k, interpret=interpret)
+        s, i = shortlist_topk_pallas(*args, k)
     else:
         s, i = _shortlist_ref_jit(*args, k)
     return np.asarray(s), np.asarray(i)

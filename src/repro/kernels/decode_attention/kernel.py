@@ -39,6 +39,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import kernels
 from repro.kernels.decode_attention import tuning
 
 NEG = -3.0e38
@@ -91,8 +92,7 @@ def _kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 def decode_attention_pallas(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                             pos: jax.Array, window: int = 0,
-                            tile_t: Optional[int] = None,
-                            interpret: bool = True):
+                            tile_t: Optional[int] = None):
     """q: (B, Hq, D); caches: (B, T, Hkv, D); pos: (B,). Returns (B, Hq, D).
 
     ``tile_t=None`` resolves the KV-time tile from the measured autotune
@@ -133,7 +133,7 @@ def decode_attention_pallas(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B * Hkv, G, D), q.dtype),
-        interpret=interpret,
+        interpret=kernels.interpret(),
     )(pos.astype(jnp.int32), qf, kf, vf)
     return out.reshape(B, Hq, D)
 
@@ -171,7 +171,9 @@ def _paged_kernel(tbl_ref, pos_ref, win_ref, q_ref, k_ref, v_ref, o_ref,
             s = softcap * jnp.tanh(s / softcap)
         kpos = pi * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         mask = kpos <= pos
-        mask = mask & jnp.where(win > 0, pos - kpos < win, True)
+        # boolean arithmetic only: Mosaic cannot lower a select on i1
+        # vectors, which is what jnp.where over booleans becomes
+        mask = mask & ((win <= 0) | (pos - kpos < win))
         s = jnp.where(mask, s, NEG)
 
         m_prev = m_scr[...][:, 0]
@@ -195,8 +197,7 @@ def _paged_kernel(tbl_ref, pos_ref, win_ref, q_ref, k_ref, v_ref, o_ref,
 def paged_decode_attention_pallas(q: jax.Array, k_pages: jax.Array,
                                   v_pages: jax.Array, table: jax.Array,
                                   pos: jax.Array, window=0,
-                                  softcap: float = 0.0,
-                                  interpret: bool = True):
+                                  softcap: float = 0.0):
     """q: (B, Hq, D); pages: (N, P, Hkv, D); table: (B, MP) int32 physical
     page per logical page (-1 = unmapped); pos: (B,). Returns (B, Hq, D).
 
@@ -246,7 +247,7 @@ def paged_decode_attention_pallas(q: jax.Array, k_pages: jax.Array,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B * Hkv, G, D), q.dtype),
-        interpret=interpret,
+        interpret=kernels.interpret(),
     )(tbl, pos.astype(jnp.int32), win, qf, kf, vf)
     return out.reshape(B, Hq, D)
 
@@ -286,7 +287,7 @@ def _paged_prefill_kernel(tbl_ref, pos_ref, win_ref, q_ref, k_ref, v_ref,
         # row r is query (r // G): its own causal frontier is pos + r // G
         qpos = pos + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // group
         mask = kpos <= qpos
-        mask = mask & jnp.where(win > 0, qpos - kpos < win, True)
+        mask = mask & ((win <= 0) | (qpos - kpos < win))
         s = jnp.where(mask, s, NEG)
 
         m_prev = m_scr[...][:, 0]
@@ -310,8 +311,7 @@ def _paged_prefill_kernel(tbl_ref, pos_ref, win_ref, q_ref, k_ref, v_ref,
 def paged_prefill_attention_pallas(q: jax.Array, k_pages: jax.Array,
                                    v_pages: jax.Array, table: jax.Array,
                                    pos: jax.Array, window=0,
-                                   softcap: float = 0.0,
-                                   interpret: bool = True):
+                                   softcap: float = 0.0):
     """Paged flash-prefill: q (B, S, Hq, D) with query j of slot b at
     absolute position ``pos[b] + j``; pages (N, P, Hkv, D); table (B, MP);
     pos (B,). Returns (B, S, Hq, D).
@@ -363,7 +363,7 @@ def paged_prefill_attention_pallas(q: jax.Array, k_pages: jax.Array,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B * Hkv, S * G, D), q.dtype),
-        interpret=interpret,
+        interpret=kernels.interpret(),
     )(tbl, pos.astype(jnp.int32), win, qf, kf, vf)
     out = out.reshape(B, Hkv, S, G, D).transpose(0, 2, 1, 3, 4)
     return out.reshape(B, S, Hq, D)

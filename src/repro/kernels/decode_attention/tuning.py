@@ -3,10 +3,11 @@
 The dense decode kernel tiles the KV-time axis; the best tile trades VMEM
 residency against grid-step overhead and depends on the cache depth and
 dtype (bf16 tiles stream twice the elements per byte).  Instead of the old
-hardcoded ``tile_t=512``, callers resolve the tile from a small measured
-table keyed by ``(dtype, cache-depth bucket)`` — numbers from a TPUv5e
-sweep of ``benchmarks/kernel_bench.py`` — with ``DEFAULT_TILE_T`` as the
-fallback for unmeasured points.  For the *paged* kernels the time tile is
+hardcoded ``tile_t=512``, callers resolve the tile from a small table keyed
+by ``(dtype, cache-depth bucket)``, with ``DEFAULT_TILE_T`` as the fallback
+for points not in it.  NOT MEASURED ON A CHIP: the only sweep behind the
+table timed the jnp oracle on a CPU (``benchmarks/kernel_bench.py``), so
+the entries are untested guesses until a chip run tunes them.  For the *paged* kernels the time tile is
 pinned to the page size by construction, so ``page_size`` (when given)
 rounds the pick down to a whole number of pages.
 
@@ -22,9 +23,9 @@ import jax.numpy as jnp
 DEFAULT_TILE_T = 512
 
 # (canonical dtype name, cache-depth bucket) -> tile_t.  Buckets are the
-# power-of-two depth the cache pads to; measured on v5e interpret-parity
-# shapes (B*Hkv grid rows saturate well before depth matters below 512).
-_MEASURED = {
+# power-of-two depth the cache pads to.  Not measured on a chip (see the
+# module docstring).
+_TABLE = {
     ("bfloat16", 512): 256,
     ("bfloat16", 1024): 512,
     ("bfloat16", 2048): 512,
@@ -46,13 +47,13 @@ def tile_choice(max_len: int, dtype, page_size: Optional[int] = None
                 ) -> Tuple[int, str]:
     """Resolve ``(tile_t, source)`` for a cache of depth ``max_len``.
 
-    ``source`` is ``"measured"`` when the (dtype, depth-bucket) point is in
+    ``source`` is ``"table"`` when the (dtype, depth-bucket) point is in
     the table and ``"default"`` otherwise — benchmark output discloses it.
     """
     name = jnp.dtype(dtype).name
     key = (name, _bucket(max_len))
-    tile, source = _MEASURED.get(key, DEFAULT_TILE_T), \
-        ("measured" if key in _MEASURED else "default")
+    tile, source = _TABLE.get(key, DEFAULT_TILE_T), \
+        ("table" if key in _TABLE else "default")
     if page_size is not None and page_size > 0:
         tile = max(page_size, tile // page_size * page_size)
     return min(tile, _bucket(max_len)), source

@@ -16,6 +16,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import kernels
+
 NEG = -3.0e38
 
 
@@ -67,7 +69,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
                            window: int = 0, tile_q: int = 128,
-                           tile_k: int = 128, interpret: bool = True):
+                           tile_k: int = 128):
     """q,k,v: (BH, S, D) -> (BH, S, D). Causal (+ optional sliding window)."""
     BH, S, D = q.shape
     tile_q = min(tile_q, S)
@@ -96,6 +98,6 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((tile_q, 1), jnp.float32),
             pltpu.VMEM((tile_q, D), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=kernels.interpret(),
     )(qp, kp, vp)
     return out[:, :S]

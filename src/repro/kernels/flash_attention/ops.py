@@ -15,8 +15,7 @@ from repro.kernels.flash_attention.kernel import flash_attention_pallas
 from repro.kernels.flash_attention.ref import flash_attention_ref
 
 
-def flash_attention(q, k, v, window: int = 0, use_pallas: bool = False,
-                    interpret: bool = True):
+def flash_attention(q, k, v, window: int = 0, use_pallas: bool = False):
     """q: (B, S, Hq, hd); k,v: (B, S, Hkv, hd) -> (B, S, Hq, hd)."""
     B, S, Hq, hd = q.shape
     Hkv = k.shape[2]
@@ -29,7 +28,7 @@ def flash_attention(q, k, v, window: int = 0, use_pallas: bool = False,
     vf = v.transpose(0, 2, 1, 3).reshape(B * Hq, S, hd)
     fn = flash_attention_pallas if use_pallas else _ref_jit
     if use_pallas:
-        of = fn(qf, kf, vf, window=window, interpret=interpret)
+        of = fn(qf, kf, vf, window=window)
     else:
         of = fn(qf, kf, vf, window)
     return of.reshape(B, Hq, S, hd).transpose(0, 2, 1, 3)

@@ -14,12 +14,8 @@ from typing import Optional, Tuple
 
 import jax
 
-# jax >= 0.5 exposes shard_map at top level; earlier versions under
-# jax.experimental — model code imports it from here
-try:
-    shard_map = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map
+# model code imports shard_map from here
+shard_map = jax.shard_map
 
 
 @dataclasses.dataclass(frozen=True)

@@ -1,14 +1,18 @@
 """Serving launcher: scheduler demo + the OpenAI-compatible HTTP front door.
 
-Two entry points share this module:
+Both entry points serve one architecture at its published width (``--arch``,
+bf16, random weights from seed 0; no weights are downloaded) on an
+``Engine`` with the Pallas kernels on — compiled on a TPU, interpreted
+elsewhere (``repro.kernels.interpret``):
 
-* ``python -m repro.launch.serve --arch gemma-2b`` — the historical demo:
-  the continuous-batching scheduler over a stream of synthetic requests
-  against a (reduced, CPU) engine, the same Engine/Scheduler pair the
-  LLMBridge model pool uses.
+* ``python -m repro.launch.serve --arch qwen2-1.5b --paged`` — the
+  continuous-batching scheduler over a stream of synthetic requests that
+  share a course-prompt prefix, the same Engine/Scheduler pair the LLMBridge
+  model pool uses.
 
-* ``python -m repro.launch.serve --http 8000`` — a stdlib HTTP server
-  exposing LLMBridge behind the OpenAI wire surface:
+* ``python -m repro.launch.serve --http 8000 --arch qwen2-1.5b`` — a stdlib
+  HTTP server exposing LLMBridge, over a pool of that one engine-backed
+  model, behind the OpenAI wire surface:
 
   - ``POST /v1/chat/completions`` — maps the JSON body through
     ``ChatCompletionRequest.from_wire``/``to_proxy`` onto the intent API.
@@ -19,17 +23,22 @@ Two entry points share this module:
     Without ``stream`` the full ``ChatCompletionResponse`` is returned as
     one JSON body.  LLMBridge intents ride ``x_``-prefixed extension
     fields (``x_max_cost``, ``x_preference``, ...) and the disclosure
-    metadata comes back under ``x_llmbridge``.
+    metadata comes back under ``x_llmbridge``.  A request whose engine
+    failed is a failure on the wire too: 502 with an error envelope, or an
+    SSE ``error`` frame and no ``[DONE]``.
   - ``GET /v1/models`` — the model pool, OpenAI list shape.
 
   Point any OpenAI client at it::
 
       client = openai.OpenAI(base_url="http://localhost:8000/v1",
                              api_key="unused")
+
+  ``make_server(bridge)`` fronts any bridge, engine-backed or SIM.
 """
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import math
 import signal
@@ -40,9 +49,19 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from repro.core.api import (ChatCompletionChunk, ChatCompletionRequest,
                             ChatCompletionResponse)
 from repro.core.overload import OverloadError
+from repro.launch import compile_cache
 
 
 # -- OpenAI-compatible HTTP front door ----------------------------------------
+
+def _provider_error(resp):
+    """The failure a response stands for, or None: the proxy resolves an
+    exhausted provider (a raising engine among them) to model ``"error"``
+    so a batch lives on — on the wire it must read as a failure."""
+    if resp is not None and resp.metadata.model_used == "error":
+        return resp.text
+    return None
+
 
 def make_server(bridge, host: str = "127.0.0.1", port: int = 8000
                 ) -> ThreadingHTTPServer:
@@ -159,6 +178,11 @@ def make_server(bridge, host: str = "127.0.0.1", port: int = 8000
                                  model=creq.model)
                 else:
                     resp = bridge.request(preq)
+                    failure = _provider_error(resp)
+                    if failure is not None:
+                        self._error(502, failure, etype="server_error",
+                                    ecode="provider_error")
+                        return
                     out = ChatCompletionResponse.from_proxy(
                         resp, rid=rid, created=created, model=creq.model)
                     self._json(200, out.to_wire())
@@ -181,6 +205,15 @@ def make_server(bridge, host: str = "127.0.0.1", port: int = 8000
             first = True
             try:
                 for chunk in gen:
+                    failure = _provider_error(chunk.response)
+                    if failure is not None:
+                        err = {"error": {"message": failure,
+                                         "type": "server_error",
+                                         "code": "provider_error"}}
+                        self.wfile.write(b"data: " + json.dumps(err).encode()
+                                         + b"\n\n")
+                        self.wfile.flush()
+                        return
                     wire = ChatCompletionChunk.from_stream(
                         chunk, rid=rid, created=created, model=model,
                         first=first).to_wire()
@@ -226,22 +259,55 @@ def install_drain_handler(bridge, server, grace: float = 2.0) -> bool:
         return False
 
 
-def serve_http(host: str, port: int, data_dir=None) -> None:
-    """Build a SIM-pool bridge and serve the OpenAI surface until ^C/SIGTERM.
+def build_engine(arch: str, seed: int = 0):
+    """An ``Engine`` of ``arch`` at its published width in bf16, random
+    weights drawn on the device from ``seed``, Pallas kernels on."""
+    import jax
+
+    from repro import configs
+    from repro.models import init_model
+    from repro.serving.engine import Engine
+
+    cfg = dataclasses.replace(configs.get(arch), use_pallas=True)
+    params = jax.jit(init_model, static_argnums=0)(
+        cfg, jax.random.PRNGKey(seed))
+    return Engine(cfg, params, max_len=256)
+
+
+def open_front_door(engine, host: str = "127.0.0.1", port: int = 8000,
+                    data_dir=None):
+    """``(bridge, server)``: the OpenAI front door over an LLMBridge whose
+    pool is one ``engine``-backed model, built and bound but not started.
+    Every answer is decoded by ``engine`` or served from the semantic cache
+    (whose vector search runs the ``cache_topk`` kernels) — no SIM model
+    can stand in.
 
     The front door runs with overload control ON: under sustained load the
     bridge browns out (degrade -> cache-only -> shed) and this surface
     answers 429/503 + ``Retry-After`` instead of queueing unboundedly.
     With ``data_dir`` the bridge is crash-safe (WAL ledger + persistent
-    cache) and SIGTERM drains gracefully: shed new work, settle in-flight,
-    fsync journals, final snapshot."""
-    from repro.core import build_bridge
-    bridge = build_bridge(data_dir=data_dir)
+    cache)."""
+    from repro.core import ModelPool, build_bridge, pool_model_from_config
+    from repro.data.tokenizer import ByteTokenizer
+
+    model = pool_model_from_config(engine.cfg, engine=engine,
+                                   tokenizer=ByteTokenizer())
+    bridge = build_bridge(pool=ModelPool([model]), use_pallas_cache=True,
+                          data_dir=data_dir)
     bridge.enable_overload()
-    server = make_server(bridge, host=host, port=port)
+    return bridge, make_server(bridge, host=host, port=port)
+
+
+def serve_http(host: str, port: int, arch: str, data_dir=None) -> None:
+    """Serve the OpenAI surface over an ``arch`` engine until ^C/SIGTERM.
+    SIGTERM drains gracefully: shed new work, settle in-flight, fsync
+    journals, final snapshot."""
+    bridge, server = open_front_door(build_engine(arch), host=host,
+                                     port=port, data_dir=data_dir)
     install_drain_handler(bridge, server)
     bound = server.server_address
     print(f"LLMBridge OpenAI-compatible surface on http://{bound[0]}:{bound[1]}/v1")
+    print(f"  model pool: {arch} (engine-backed)")
     print("  POST /v1/chat/completions   (stream: true -> SSE)")
     print("  GET  /v1/models")
     if data_dir is not None:
@@ -259,20 +325,14 @@ def serve_http(host: str, port: int, data_dir=None) -> None:
 # -- scheduler demo -----------------------------------------------------------
 
 def demo(args) -> None:
-    import jax
     import jax.numpy as jnp
 
-    from repro import configs
     from repro.data.tokenizer import ByteTokenizer
-    from repro.models import init_model
-    from repro.serving.engine import Engine
     from repro.serving.sampler import SamplerConfig
     from repro.serving.scheduler import Request, Scheduler
 
-    cfg = configs.get_reduced(args.arch)
-    params = init_model(cfg, jax.random.PRNGKey(0))
+    engine = build_engine(args.arch)
     tok = ByteTokenizer()
-    engine = Engine(cfg, params, max_len=128)
     sched = Scheduler(engine, n_slots=args.slots,
                       sampler=SamplerConfig(temperature=args.temperature, top_k=40),
                       paged=args.paged, page_size=args.page_size)
@@ -291,7 +351,7 @@ def demo(args) -> None:
     dt = time.time() - t0
     toks = sum(len(r.generated) for r in done)
     print(f"served {len(done)} requests / {toks} tokens in {dt:.1f}s "
-          f"({toks/dt:.1f} tok/s, slots={args.slots})")
+          f"({toks/dt:.1f} tok/s, slots={args.slots}, compile included)")
     if args.paged:
         print(f"  paged: prefill_tokens={sched.prefill_tokens} "
               f"shared_tokens={sched.shared_tokens} "
@@ -305,7 +365,9 @@ def main() -> None:
     from repro import configs
 
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="qwen2-1.5b", choices=configs.ARCH_IDS)
+    ap.add_argument("--arch", default="qwen2-1.5b", choices=configs.ARCH_IDS,
+                    help="architecture served at its published width "
+                         "(bf16, random weights from seed 0)")
     ap.add_argument("--requests", type=int, default=12)
     ap.add_argument("--users", type=int, default=4)
     ap.add_argument("--slots", type=int, default=4)
@@ -323,8 +385,9 @@ def main() -> None:
                     help="durable state directory (WAL ledger + persistent "
                          "semantic cache + graceful SIGTERM drain)")
     args = ap.parse_args()
+    compile_cache.enable()
     if args.http is not None:
-        serve_http(args.host, args.http, data_dir=args.data_dir)
+        serve_http(args.host, args.http, args.arch, data_dir=args.data_dir)
     else:
         demo(args)
 

@@ -323,19 +323,16 @@ def _decode_attn_paged(q, k_new, v_new, cache, cfg: ModelConfig, *, window):
         k_new.astype(cache["k_pages"].dtype))
     v_pages = cache["v_pages"].at[phys, off].set(
         v_new.astype(cache["v_pages"].dtype))
-    interpret = jax.default_backend() != "tpu"
     Hq, hd = q.shape[2], q.shape[3]
     if S == 1:
         out = da_ops.paged_decode_attention(
             q[:, 0], k_pages, v_pages, cache["table"], pos, window=window,
-            softcap=cfg.logit_softcap, use_pallas=cfg.use_pallas,
-            interpret=interpret)
+            softcap=cfg.logit_softcap, use_pallas=cfg.use_pallas)
         out = out.reshape(B, 1, Hq * hd)
     else:
         out = da_ops.paged_prefill_attention(
             q, k_pages, v_pages, cache["table"], pos, window=window,
-            softcap=cfg.logit_softcap, use_pallas=cfg.use_pallas,
-            interpret=interpret)
+            softcap=cfg.logit_softcap, use_pallas=cfg.use_pallas)
         out = out.reshape(B, S, Hq * hd)
     new_cache = {"k_pages": k_pages, "v_pages": v_pages,
                  "table": cache["table"], "pos": pos + S}

@@ -198,13 +198,17 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int) -> Dict:
     raise ValueError(fam)
 
 
+#: families whose layer state is attention KV only, so it can live in pages
+PAGED_FAMILIES = ("dense", "vlm", "moe")
+
+
 def init_paged_cache(cfg: ModelConfig, batch: int, n_pages: int,
                      page_size: int, max_pages: int) -> Dict:
     """Paged KV cache (attention-only families): a global page pool + per-
     slot page tables instead of one dense (B, max_len) region per slot.  The
     serving scheduler owns the page allocator / prefix trie metadata
     (serving/kv_cache.PagePool); recurrent families have no paged layout."""
-    if cfg.family not in ("dense", "vlm", "moe"):
+    if cfg.family not in PAGED_FAMILIES:
         raise ValueError(f"paged KV cache requires an attention-only family, "
                          f"got {cfg.family!r}")
     return {"paged": L.init_paged_kv_cache(cfg, batch, n_pages, page_size,

@@ -16,9 +16,9 @@ if importlib.util.find_spec("hypothesis") is None:
         if any(line.startswith(("import hypothesis", "from hypothesis"))
                for line in p.read_text().splitlines()))
 elif os.environ.get("CI"):
-    # derandomized draws on CI: every matrix leg (python x jax version) sees
-    # the same examples, so a leg-specific failure is a real version issue,
-    # not a different random draw — no plugin flags needed
+    # derandomized draws on CI: every run sees the same examples, so a CI
+    # failure reproduces locally instead of hiding behind a random draw —
+    # no plugin flags needed
     from hypothesis import settings
 
     settings.register_profile("ci", derandomize=True, deadline=None)

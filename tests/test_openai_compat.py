@@ -254,3 +254,48 @@ class TestHTTP:
         r = _post(server, {"model": "auto", "messages": []})
         assert r.status == 400
         assert "error" in json.loads(r.read())
+
+
+# -- an engine that fails is a failure on the wire ------------------------------
+
+class _DownTokenizer:
+    def encode(self, text, bos=True):
+        raise RuntimeError("engine down")
+
+    def decode(self, ids):
+        return ""
+
+
+@pytest.fixture
+def broken_server():
+    """The front door over a one-model pool whose engine raises: nothing
+    else in the pool can answer in its place."""
+    from repro.core import ModelPool, PoolModel
+    from repro.launch.serve import make_server
+    pool = ModelPool([PoolModel(name="broken", active_params=int(1e9),
+                                capability=0.5, engine=object(),
+                                tokenizer=_DownTokenizer())])
+    srv = make_server(build_bridge(pool=pool), port=0)
+    t = threading.Thread(target=srv.serve_forever, daemon=True)
+    t.start()
+    yield srv.server_address
+    srv.shutdown()
+
+
+class TestEngineFailure:
+    MSG = [{"role": "user", "content": "does the engine answer?"}]
+
+    def test_buffered_is_502(self, broken_server):
+        r = _post(broken_server, {"model": "auto", "user": "u",
+                                  "messages": self.MSG})
+        body = json.loads(r.read())
+        assert r.status == 502
+        assert body["error"]["code"] == "provider_error"
+        assert "broken" in body["error"]["message"]
+
+    def test_stream_ends_in_error_not_done(self, broken_server):
+        r = _post(broken_server, {"model": "auto", "user": "u",
+                                  "stream": True, "messages": self.MSG})
+        frames = _sse_frames(r)
+        assert "DONE" not in frames
+        assert frames[-1]["error"]["code"] == "provider_error"

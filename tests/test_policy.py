@@ -7,6 +7,8 @@ scheduler admission, and batched verification routing.
 property-based modules are skipped at collection; the max_cost property
 tests live in test_policy_properties.py.)
 """
+import types
+
 import numpy as np
 import pytest
 
@@ -443,8 +445,10 @@ class _FakeTokenizer:
 class _FakeEngine:
     """Counts batched-cache creations (slot pool + one per admitted prefill
     group) and prefill calls: a batched Scheduler refill must admit the whole
-    continuous batch with ONE prefill."""
+    continuous batch with ONE prefill.  Its family has no paged KV layout,
+    so the adapter schedules it on the dense per-slot cache."""
     max_len = 64
+    cfg = types.SimpleNamespace(family="ssm")
 
     def __init__(self):
         self.batch_caches = 0

@@ -162,6 +162,22 @@ def test_adapter_generate_batch_spec_wiring(engine, small_engine):
     assert s["enabled"] and s["rounds"] > 0 and s["emitted"] > 0
 
 
+def test_adapter_schedules_attention_families_paged(engine):
+    """Every engine-backed adapter path decodes on the paged cache (and its
+    paged attention kernels) when the family has one; recurrent families
+    keep the dense per-slot cache."""
+    from repro.core import PoolModel
+    from repro.core.model_adapter import ModelAdapter
+    from repro.data.tokenizer import ByteTokenizer
+    cfg = configs.get_reduced("xlstm-350m")
+    recurrent = Engine(cfg, init_model(cfg, jax.random.PRNGKey(0)),
+                       max_len=32)
+    for eng, paged in ((engine, True), (recurrent, False)):
+        m = PoolModel(name=eng.cfg.name, active_params=int(1e9),
+                      capability=0.5, engine=eng, tokenizer=ByteTokenizer())
+        assert ModelAdapter._scheduler(m, n_slots=2).paged is paged
+
+
 def test_draft_engine_rejects_cursorless_family():
     """Recurrent drafts have no dense KV cursor to rewind -> constructor
     refuses instead of silently corrupting proposals."""
