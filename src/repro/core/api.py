@@ -52,6 +52,8 @@ import threading
 import time
 from typing import Any, Dict, Iterator, List, Mapping, Optional, Sequence
 
+from repro.core import telemetry
+
 
 class ServiceType(str, enum.Enum):
     """v1 delegation presets (paper Table 2), kept as named intents.
@@ -153,8 +155,9 @@ class Usage:
 class StageRecord:
     """One pipeline stage's disclosure: what it decided and what it cost.
 
-    ``duration`` is wall-clock seconds in the proxy process (in batch mode,
-    the stage's batch wall-time divided evenly across its live requests);
+    ``duration`` is the wall time in seconds of the stage's
+    ``proxy.stage.<name>`` telemetry span (in batch mode, the stage's batch
+    span divided evenly across its live requests);
     ``decision`` is the stage's one-token summary (``hit``/``miss``, routed
     model, context strategy, ...); ``cost_delta`` is the request-cost
     increase attributable to the stage.
@@ -266,6 +269,10 @@ class StreamChunk:
     token_ids: List[int] = dataclasses.field(default_factory=list)
     final: bool = False
     response: Optional[ProxyResponse] = None
+    # the chunk's place in its stream (0, 1, ...), which ``TokenStream``
+    # assigns; -1 on the terminal chunk and chunks built elsewhere.  The
+    # ``stream.emit`` and ``http.sse_write`` spans of a chunk carry it.
+    seq: int = -1
 
 
 class _StreamError:
@@ -322,6 +329,10 @@ class TokenStream:
         """Push one delta.  Returns False iff the consumer cancelled — the
         producer must stop decoding (the chunk may or may not have been
         delivered; it is not counted as emitted after a cancel)."""
+        with telemetry.span("stream.emit", seq=self.chunks_emitted):
+            return self._emit(text, token_ids)
+
+    def _emit(self, text: str, token_ids: Sequence[int]) -> bool:
         if self._cancel.is_set():
             return False
         if (self.idle_timeout is not None and self._waiters == 0
@@ -332,7 +343,8 @@ class TokenStream:
             self.cancel_reason = "idle"
             self._cancel.set()
             return False
-        chunk = StreamChunk(text=text, token_ids=list(token_ids))
+        chunk = StreamChunk(text=text, token_ids=list(token_ids),
+                            seq=self.chunks_emitted)
         while True:
             try:
                 self._q.put(chunk, timeout=self._POLL_S)

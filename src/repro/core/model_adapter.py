@@ -41,11 +41,13 @@ the answering provider and retries/hedges can never double-charge.
 from __future__ import annotations
 
 import dataclasses
+import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro.core import telemetry
 from repro.core.api import Usage
 from repro.core.providers import ProviderError, ProviderFleet
 from repro.core.workload import Query, Workload, capability_from_params
@@ -194,9 +196,12 @@ class ModelAdapter:
         # background threads must not interleave draws with the foreground
         # request path, both for thread-safety and for reproducibility
         self.background_rng = np.random.default_rng(seed + 1000)
-        # per-model speculative-decode telemetry, accumulated across batched
-        # decodes (proxy.stats()["serving"] and Metadata.spec_* read this)
+        # per-model serving telemetry, accumulated over every scheduler the
+        # model's requests built: prefilled and shared prompt tokens, peak
+        # live slots, and speculative-decode totals when it drafts
+        # (proxy.stats()["serving"] and Metadata.spec_* read this)
         self.serving_stats: Dict[str, Dict[str, Any]] = {}
+        self._serving_lock = threading.Lock()   # streams finish on threads
         # the reliability layer: every pool model is a registered provider.
         # With no FaultSpec injected the fleet is a passive health tap (zero
         # extra RNG draws, bit-identical behaviour); chaos specs or
@@ -337,23 +342,25 @@ class ModelAdapter:
         dense cache for recurrent families, which have no paged layout."""
         from repro.models.transformer import PAGED_FAMILIES
         from repro.serving.scheduler import Scheduler
-        if model.engine.cfg.family not in PAGED_FAMILIES:
-            return Scheduler(model.engine, n_slots=n_slots)
-        draft = None
-        if model.draft_engine is not None:
-            from repro.serving.engine import DraftEngine
-            draft = DraftEngine(model.draft_engine, n_slots=n_slots,
-                                max_len=model.engine.max_len)
-        return Scheduler(model.engine, n_slots=n_slots, paged=True,
-                         draft=draft, spec_k=model.spec_k)
+        with telemetry.span("adapter.scheduler_build"):
+            if model.engine.cfg.family not in PAGED_FAMILIES:
+                return Scheduler(model.engine, n_slots=n_slots)
+            draft = None
+            if model.draft_engine is not None:
+                from repro.serving.engine import DraftEngine
+                draft = DraftEngine(model.draft_engine, n_slots=n_slots,
+                                    max_len=model.engine.max_len)
+            return Scheduler(model.engine, n_slots=n_slots, paged=True,
+                             draft=draft, spec_k=model.spec_k)
 
     @staticmethod
     def _prompt_ids(model: PoolModel, prompt: str):
         """The prompt's last tokens, leaving the engine room to decode."""
         import jax.numpy as jnp
-        ids = model.tokenizer.encode(prompt)
-        keep = min(64, model.engine.max_len - 1)
-        return jnp.asarray(ids[-keep:], jnp.int32)
+        with telemetry.span("adapter.tokenize"):
+            ids = model.tokenizer.encode(prompt)
+            keep = min(64, model.engine.max_len - 1)
+            return jnp.asarray(ids[-keep:], jnp.int32)
 
     # -- streaming generation (the incremental token channel) ------------------
     def _stream_generate(self, model: PoolModel, prompt: str,
@@ -396,7 +403,8 @@ class ModelAdapter:
         while sched.pending() and not cancelled:
             for _req, new_toks, _done in sched.step_stream():
                 emitted.extend(new_toks)
-                full = model.tokenizer.decode(emitted)
+                with telemetry.span("adapter.detokenize"):
+                    full = model.tokenizer.decode(emitted)
                 stable = full.rstrip("\ufffd")
                 delta, text = stable[len(text):], stable
                 if not stream.emit(delta, token_ids=new_toks):
@@ -406,8 +414,7 @@ class ModelAdapter:
         if not cancelled and len(full) > len(text):
             stream.emit(full[len(text):])
             text = full
-        if model.draft_engine is not None:
-            self._note_spec(model.name, sched.spec_summary())
+        self._note_scheduler(model, sched)
         return text, (len(emitted) if cancelled else out_tokens)
 
     def _stream_sim(self, model: PoolModel, prompt: str, out_tokens: int,
@@ -544,8 +551,7 @@ class ModelAdapter:
         else:
             done = sched.run_to_completion()
             self._observe_occupancy(sched)
-        if model.draft_engine is not None:
-            self._note_spec(model.name, sched.spec_summary())
+        self._note_scheduler(model, sched)
         texts = {r.rid: model.tokenizer.decode(r.generated) for r in done}
         # a rid missing from finished was cancelled while still queued:
         # nothing decoded, nothing to charge
@@ -568,20 +574,30 @@ class ModelAdapter:
             live = sum(1 for s in sched.slots if s is not None)
             ov.observe("pages", live / max(1, len(sched.slots)))
 
-    def _note_spec(self, name: str, summary: Dict[str, Any]) -> None:
-        """Fold one batch's spec_summary into the per-model running totals."""
-        agg = self.serving_stats.setdefault(name, {
-            "rounds": 0, "proposed": 0, "accepted": 0, "emitted": 0,
-            "draft_time": 0.0, "verify_time": 0.0})
-        for key in ("rounds", "proposed", "accepted", "emitted",
-                    "draft_time", "verify_time"):
-            agg[key] += summary[key]
-        agg["enabled"] = summary["enabled"]
-        agg["disabled_reason"] = summary["disabled_reason"]
-        agg["acceptance_rate"] = (agg["accepted"] / agg["proposed"]
-                                  if agg["proposed"] else 0.0)
-        agg["tokens_per_round"] = (agg["emitted"] / agg["rounds"]
-                                   if agg["rounds"] else 0.0)
+    def _note_scheduler(self, model: PoolModel, sched) -> None:
+        """Fold a finished scheduler's counters (and, when it drafted, its
+        speculation summary) into the model's running totals: each request
+        builds its own scheduler, whose counters would die with it."""
+        with self._serving_lock:
+            agg = self.serving_stats.setdefault(model.name, {
+                "schedulers": 0, "prefill_tokens": 0, "shared_tokens": 0,
+                "peak_live": 0})
+            agg["schedulers"] += 1
+            agg["prefill_tokens"] += sched.prefill_tokens
+            agg["shared_tokens"] += sched.shared_tokens
+            agg["peak_live"] = max(agg["peak_live"], sched.peak_live)
+            if model.draft_engine is None:
+                return
+            summary = sched.spec_summary()
+            for key in ("rounds", "proposed", "accepted", "emitted",
+                        "draft_time", "verify_time"):
+                agg[key] = agg.get(key, 0) + summary[key]
+            agg["enabled"] = summary["enabled"]
+            agg["disabled_reason"] = summary["disabled_reason"]
+            agg["acceptance_rate"] = (agg["accepted"] / agg["proposed"]
+                                      if agg["proposed"] else 0.0)
+            agg["tokens_per_round"] = (agg["emitted"] / agg["rounds"]
+                                       if agg["rounds"] else 0.0)
 
     # -- verification-based selection (paper §3.3) -----------------------------
     def resolve_triple(self, m1: Optional[PoolModel] = None,

@@ -8,10 +8,10 @@ functions per request.  This module makes that composition explicit:
 * ``Stage``          — one middlebox function (cache / context / route /
   model / prefetch / decline); each consumes and produces a ``RequestState``;
 * ``PromptPipeline`` — an ordered stage list with single-request (``run``)
-  and batch-first (``run_batch``) execution.  Both wrap every stage with a
-  wall-clock timer and append a ``StageRecord`` (name, duration, decision,
-  cost delta) to the state — the raw material for ``Metadata.stage_records``
-  and ``proxy.stats()``.
+  and batch-first (``run_batch``) execution.  Both run every stage in a
+  ``proxy.stage.<name>`` telemetry span and append a ``StageRecord``
+  (name, the span's duration, decision, cost delta) to the state — the
+  raw material for ``Metadata.stage_records`` and ``proxy.stats()``.
 
 Pipelines are produced by the ``PolicyCompiler`` (``core/policy.py``): the
 seven ``ServiceType`` presets and arbitrary ``Constraints``/``Preference``
@@ -36,6 +36,7 @@ import dataclasses
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence
 
+from repro.core import telemetry
 from repro.core.api import (Metadata, ProxyRequest, ProxyResponse, ServiceType,
                             StageRecord, Usage)
 from repro.core.context_manager import ContextManager, Message
@@ -568,9 +569,10 @@ def _resolve_timeout(proxy, state: RequestState, stage_name: str) -> None:
 class PromptPipeline:
     """An ordered stage composition with sequential and batch execution.
 
-    Both modes time every stage and append a ``StageRecord`` per live
-    request — per-stage wall-time, the stage's decision, and the cost delta
-    it caused — feeding ``Metadata.stage_records`` and ``proxy.stats()``.
+    Both modes run every stage in a ``proxy.stage.<name>`` span and append
+    a ``StageRecord`` per live request — the span's wall time, the stage's
+    decision, and the cost delta it caused — feeding
+    ``Metadata.stage_records`` and ``proxy.stats()``.
     """
 
     def __init__(self, stages: Sequence[Stage]):
@@ -590,12 +592,13 @@ class PromptPipeline:
                 _resolve_timeout(proxy, state, stage.name)
                 break
             cost_before = state.cost()
-            t0 = time.perf_counter()
-            stage.run(proxy, state)
-            dt = time.perf_counter() - t0
+            with telemetry.span("proxy.stage." + stage.name,
+                                rid=state.req.request_id or None) as sp:
+                stage.run(proxy, state)
             state.stages_run.append(stage.name)
             state.records.append(StageRecord(
-                name=stage.name, duration=dt, decision=stage.decision(state),
+                name=stage.name, duration=sp.t1 - sp.t0,
+                decision=stage.decision(state),
                 cost_delta=state.cost() - cost_before))
         return state
 
@@ -618,9 +621,9 @@ class PromptPipeline:
             if not live:
                 continue
             costs_before = [s.cost() for s in live]
-            t0 = time.perf_counter()
-            stage.run_batch(proxy, live)
-            share = (time.perf_counter() - t0) / len(live)
+            with telemetry.span("proxy.stage." + stage.name) as sp:
+                stage.run_batch(proxy, live)
+            share = (sp.t1 - sp.t0) / len(live)
             for s, cb in zip(live, costs_before):
                 s.stages_run.append(stage.name)
                 s.records.append(StageRecord(

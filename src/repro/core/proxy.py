@@ -51,6 +51,7 @@ from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.core import telemetry
 from repro.core.api import (Metadata, ProxyRequest, ProxyResponse, ServiceType,
                             StreamChunk, TokenStream, Usage)
 from repro.core.cache import SemanticCache
@@ -421,11 +422,15 @@ class LLMBridge:
         state = self._state_for(req)
         sink = TokenStream(maxsize=buffer)
         state.stream = sink
+        # the worker's spans are children of the caller's (the front
+        # door's ``http.request``) and serve the same request
+        parent = telemetry.current()
 
         def work() -> None:
             try:
-                state.policy.pipeline.run(self, state)
-                resp = self._finalize(state, path="request_stream")
+                with telemetry.handoff(parent, rid=req.request_id):
+                    state.policy.pipeline.run(self, state)
+                    resp = self._finalize(state, path="request_stream")
                 sink.close(response=resp)
             except BaseException as e:   # surface to the consumer, don't leak
                 self._release_hold(state)
@@ -496,6 +501,11 @@ class LLMBridge:
         fields, ledger settle, stats, context append.  ``query_tokens=False``
         preserves the historical regenerate behaviour of appending context
         without the planted token count."""
+        with telemetry.span("proxy.finalize"):
+            return self._finalize_in_span(state, path, query_tokens)
+
+    def _finalize_in_span(self, state: RequestState, path: str,
+                          query_tokens: bool) -> ProxyResponse:
         self._crash_hit("proxy.finalize.pre")
         req, resp, policy = state.req, state.response, state.policy
         resp.metadata.request_id = req.request_id or ""
@@ -713,6 +723,10 @@ class LLMBridge:
             # brownout/backpressure disclosure: current level, per-signal
             # pressure, shed counts, recent level transitions
             "overload": self.overload.snapshot(),
+            # the process's span and compile counters (core/telemetry.py):
+            # scheduler steps, live-slot steps, tokens, prefilled and
+            # shared prompt tokens, backend compiles
+            "telemetry": telemetry.counters(),
         }
         if self._admission is not None:
             out["admission"] = self._admission.stats()

@@ -200,6 +200,7 @@ def shortlist_topk_pallas(q: jax.Array, db: jax.Array, codes: jax.Array,
             pltpu.VMEM((tile_q, k), jnp.int32),
             pltpu.VMEM((tile_q, tile_l), jnp.float32),
         ],
+        name="shortlist_topk",
         interpret=kernels.interpret(),
     )(qp, dbp, codesp, slp, tmp, thp)
     s, i = out_s[:Q], out_i[:Q]
@@ -238,6 +239,7 @@ def similarity_topk_pallas(q: jax.Array, db: jax.Array, k: int,
             pltpu.VMEM((tile_q, k), jnp.float32),
             pltpu.VMEM((tile_q, k), jnp.int32),
         ],
+        name="similarity_topk",
         interpret=kernels.interpret(),
     )(qp, dbp)
     return out_s[:Q], out_i[:Q]

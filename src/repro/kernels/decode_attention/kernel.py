@@ -133,6 +133,7 @@ def decode_attention_pallas(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B * Hkv, G, D), q.dtype),
+        name="decode_attention",
         interpret=kernels.interpret(),
     )(pos.astype(jnp.int32), qf, kf, vf)
     return out.reshape(B, Hq, D)
@@ -247,6 +248,7 @@ def paged_decode_attention_pallas(q: jax.Array, k_pages: jax.Array,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B * Hkv, G, D), q.dtype),
+        name="paged_decode_attention",
         interpret=kernels.interpret(),
     )(tbl, pos.astype(jnp.int32), win, qf, kf, vf)
     return out.reshape(B, Hq, D)
@@ -363,6 +365,7 @@ def paged_prefill_attention_pallas(q: jax.Array, k_pages: jax.Array,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B * Hkv, S * G, D), q.dtype),
+        name="paged_prefill_attention",
         interpret=kernels.interpret(),
     )(tbl, pos.astype(jnp.int32), win, qf, kf, vf)
     out = out.reshape(B, Hkv, S, G, D).transpose(0, 2, 1, 3, 4)

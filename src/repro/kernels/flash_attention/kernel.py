@@ -98,6 +98,7 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((tile_q, 1), jnp.float32),
             pltpu.VMEM((tile_q, D), jnp.float32),
         ],
+        name="flash_attention",
         interpret=kernels.interpret(),
     )(qp, kp, vp)
     return out[:, :S]

@@ -46,6 +46,7 @@ import time
 import uuid
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
+from repro.core import telemetry
 from repro.core.api import (ChatCompletionChunk, ChatCompletionRequest,
                             ChatCompletionResponse)
 from repro.core.overload import OverloadError
@@ -148,6 +149,11 @@ def make_server(bridge, host: str = "127.0.0.1", port: int = 8000
                 self._error(404, f"unknown path {self.path}",
                             ecode="not_found")
                 return
+            # the root span of everything this request does, on any thread
+            with telemetry.span("http.request", rid=self._request_id()):
+                self._chat_completions()
+
+        def _chat_completions(self) -> None:
             try:
                 n = int(self.headers.get("Content-Length", 0))
                 raw = self.rfile.read(n) or b"{}"
@@ -214,13 +220,14 @@ def make_server(bridge, host: str = "127.0.0.1", port: int = 8000
                                          + b"\n\n")
                         self.wfile.flush()
                         return
-                    wire = ChatCompletionChunk.from_stream(
-                        chunk, rid=rid, created=created, model=model,
-                        first=first).to_wire()
-                    first = False
-                    self.wfile.write(b"data: " + json.dumps(wire).encode()
-                                     + b"\n\n")
-                    self.wfile.flush()
+                    with telemetry.span("http.sse_write", seq=chunk.seq):
+                        wire = ChatCompletionChunk.from_stream(
+                            chunk, rid=rid, created=created, model=model,
+                            first=first).to_wire()
+                        first = False
+                        self.wfile.write(b"data: " + json.dumps(wire).encode()
+                                         + b"\n\n")
+                        self.wfile.flush()
                 self.wfile.write(b"data: [DONE]\n\n")
                 self.wfile.flush()
             except (BrokenPipeError, ConnectionResetError):

@@ -267,11 +267,14 @@ def forward(
     x = L.apply_norm(params, x, cfg, "final_norm")
     if return_hidden:
         return x, new_cache, aux
-    logits = L.unembed(x, params["embed"] if cfg.tie_embeddings else params["unembed"],
-                       cfg.tie_embeddings)
-    if cfg.final_softcap > 0:
-        c = cfg.final_softcap
-        logits = (c * jnp.tanh(logits.astype(jnp.float32) / c)).astype(logits.dtype)
+    with jax.named_scope("logits"):
+        logits = L.unembed(
+            x, params["embed"] if cfg.tie_embeddings else params["unembed"],
+            cfg.tie_embeddings)
+        if cfg.final_softcap > 0:
+            c = cfg.final_softcap
+            logits = (c * jnp.tanh(logits.astype(jnp.float32) / c)
+                      ).astype(logits.dtype)
     return logits, new_cache, aux
 
 
@@ -282,20 +285,22 @@ def _maybe_remat(fn, cfg: ModelConfig):
 def _stack_scan(body, carry, xs, cfg: ModelConfig):
     """lax.scan over the layer stack, or a python loop when
     cfg.unroll_layers (dry-run cost calibration: XLA's HLO cost analysis
-    counts while-loop bodies once, so calibration compiles must be flat)."""
-    if not cfg.unroll_layers:
-        return jax.lax.scan(body, carry, xs)
-    length = jax.tree.leaves(xs)[0].shape[0]
-    ys = []
-    for i in range(length):
-        x_i = jax.tree.map(lambda a: a[i], xs)
-        carry, y = body(carry, x_i)
-        ys.append(y)
-    if all(len(jax.tree.leaves(y)) == 0 for y in ys):
-        stacked = ys[0]
-    else:
-        stacked = jax.tree.map(lambda *vs: jnp.stack(vs), *ys)
-    return carry, stacked
+    counts while-loop bodies once, so calibration compiles must be flat).
+    Either way the stack's ops carry the named scope ``layers``."""
+    with jax.named_scope("layers"):
+        if not cfg.unroll_layers:
+            return jax.lax.scan(body, carry, xs)
+        length = jax.tree.leaves(xs)[0].shape[0]
+        ys = []
+        for i in range(length):
+            x_i = jax.tree.map(lambda a: a[i], xs)
+            carry, y = body(carry, x_i)
+            ys.append(y)
+        if all(len(jax.tree.leaves(y)) == 0 for y in ys):
+            stacked = ys[0]
+        else:
+            stacked = jax.tree.map(lambda *vs: jnp.stack(vs), *ys)
+        return carry, stacked
 
 
 def _slice_cache(kv: Optional[Dict], reshape_groups: Optional[Tuple[int, int]] = None):

@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import telemetry
 from repro.models import apply_model, init_cache, init_paged_cache, vlm
 from repro.models.config import ModelConfig
 from repro.serving.sampler import SamplerConfig, sample
@@ -46,8 +47,10 @@ class Engine:
         self.cfg = cfg
         self.params = params
         self.max_len = max_len
-        self._prefill = jax.jit(functools.partial(prefill_step, cfg=cfg))
-        self._decode = jax.jit(functools.partial(decode_step, cfg=cfg))
+        # named after the step functions, so a device trace's programs
+        # read ``jit_prefill_step`` and ``jit_decode_step``
+        self._prefill = jax.jit(_bind_cfg(prefill_step, cfg))
+        self._decode = jax.jit(_bind_cfg(decode_step, cfg))
         # telemetry: the batched admission path must collapse a refill's
         # prefills into one call per group, and the decode loop must not
         # sync the done mask per token (benchmarks/serving_latency.py)
@@ -58,7 +61,8 @@ class Engine:
     def prefill(self, tokens: jax.Array, cache: Dict, **extras):
         self.n_prefill_calls += 1
         self.n_prefill_tokens += int(tokens.shape[0]) * int(tokens.shape[1])
-        return self._prefill(self.params, tokens, cache, **extras)
+        with telemetry.span("engine.prefill"):
+            return self._prefill(self.params, tokens, cache, **extras)
 
     def new_cache(self, batch: int, max_len: Optional[int] = None) -> Dict:
         return init_cache(self.cfg, batch, max_len or self.max_len)
@@ -71,7 +75,8 @@ class Engine:
         return init_paged_cache(self.cfg, batch, n_pages, page_size, max_pages)
 
     def decode(self, tokens: jax.Array, positions: jax.Array, cache: Dict):
-        return self._decode(self.params, tokens, positions, cache)
+        with telemetry.span("engine.decode"):
+            return self._decode(self.params, tokens, positions, cache)
 
     def generate(self, prompt: jax.Array, max_new: int,
                  sampler: SamplerConfig = SamplerConfig(),
@@ -196,6 +201,13 @@ def serve_step(params: Dict, tokens: jax.Array, positions: jax.Array,
                cache: Dict, *, cfg: ModelConfig) -> Tuple[jax.Array, Dict]:
     """Dry-run artifact for decode shapes: one token, deep KV cache."""
     return decode_step(params, tokens, positions, cache, cfg=cfg)
+
+
+def _bind_cfg(step, cfg: ModelConfig):
+    """``step`` with its config bound, keeping the step's name."""
+    bound = functools.partial(step, cfg=cfg)
+    bound.__name__ = bound.__qualname__ = step.__name__
+    return bound
 
 
 def _pow2(n: int, lo: int = 1) -> int:

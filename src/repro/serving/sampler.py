@@ -21,11 +21,12 @@ class SamplerConfig:
 
 
 def sample(logits: jax.Array, key: jax.Array, sc: SamplerConfig) -> jax.Array:
-    """logits: (B, V) -> (B,) int32."""
-    if sc.temperature <= 0.0:
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    x = logits.astype(jnp.float32) / sc.temperature
-    if sc.top_k > 0:
-        kth = jax.lax.top_k(x, sc.top_k)[0][:, -1:]
-        x = jnp.where(x < kth, -jnp.inf, x)
-    return jax.random.categorical(key, x, axis=-1).astype(jnp.int32)
+    """logits: (B, V) -> (B,) int32, under the named scope ``sampler``."""
+    with jax.named_scope("sampler"):
+        if sc.temperature <= 0.0:
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        x = logits.astype(jnp.float32) / sc.temperature
+        if sc.top_k > 0:
+            kth = jax.lax.top_k(x, sc.top_k)[0][:, -1:]
+            x = jnp.where(x < kth, -jnp.inf, x)
+        return jax.random.categorical(key, x, axis=-1).astype(jnp.int32)

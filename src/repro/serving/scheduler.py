@@ -54,6 +54,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import configs
+from repro.core import telemetry
 from repro.serving import discipline, kv_cache
 from repro.serving.engine import DraftEngine, Engine
 from repro.serving.sampler import SamplerConfig, sample
@@ -144,6 +145,7 @@ class Scheduler:
         self.prefill_tokens = 0           # real (unpadded) tokens prefilled
         self.shared_tokens = 0            # prompt tokens served from the trie
         self.peak_live = 0                # max concurrently admitted slots
+        telemetry.count("sched.built")
         # -- speculative decoding (draft-model propose, big-model verify) ----
         # a draft only engages when every correctness precondition holds;
         # anything else degrades to plain decode with the reason on record
@@ -294,6 +296,8 @@ class Scheduler:
         single = self.engine.new_cache(len(reqs), self.max_len)
         logits, single = self.engine.prefill(prompts, single)
         self.prefill_tokens += sum(lens)
+        telemetry.count("sched.prefill_tokens", sum(lens))
+        telemetry.count("sched.tokens", len(reqs))
         if S != min(lens) and "kv" in single:
             # rewind each slot's KV write cursor to its real prompt length:
             # pad KV beyond it is dead — overwritten by decode before the
@@ -424,6 +428,7 @@ class Scheduler:
                 self._tables[slot, pi] = page
             self._slot_unreserved[slot] = n_new - (n_prompt_pages - first_new)
             self.shared_tokens += suffix_start
+            telemetry.count("sched.shared_tokens", suffix_start)
             plan.append((slot, req, tokens, shared, suffix_start,
                          matched[-1] if cow else -1))
         if not plan:
@@ -477,6 +482,8 @@ class Scheduler:
         positions = starts_dev[:, None] + jnp.arange(S, dtype=jnp.int32)[None]
         logits, view = self.engine.decode(toks, positions, view)
         self.prefill_tokens += sum(suf)
+        telemetry.count("sched.prefill_tokens", sum(suf))
+        telemetry.count("sched.tokens", B)
         self.engine.n_prefill_tokens += B * S
         sl = jnp.asarray(slots, jnp.int32)
         paged = {
@@ -549,38 +556,60 @@ class Scheduler:
 
     # -- one decode step over the whole batch --------------------------------
     def step(self) -> List[Request]:
-        self._admit()
+        """Admit, then decode one token for every live slot.  The step's
+        phases are telemetry spans: ``sched.admit`` (prefill inside),
+        ``sched.map_pages``, ``sched.dispatch`` (the decode and the sampler
+        enqueued), ``sched.wait`` (the host waits for the device and reads
+        the tokens) and ``sched.update`` (the tokens' bookkeeping and
+        teardown)."""
+        with telemetry.span("sched.step"):
+            return self._step()
+
+    def _step(self) -> List[Request]:
+        with telemetry.span("sched.admit"):
+            self._admit()
         live = [s for s in self.slots if s is not None]
         if not live:
             return []
         self.peak_live = max(self.peak_live, len(live))
+        telemetry.count("sched.decode_steps")
+        telemetry.count("sched.live_slot_steps", len(live))
         if self.draft is not None:
             return self._spec_step()
         if self.paged:
-            self._map_decode_pages()
-        positions = jnp.array(
-            [[s.pos if s is not None else 0] for s in self.slots], jnp.int32)
-        logits, self.cache = self.engine.decode(self.tokens[:, None], positions, self.cache)
-        self.key, sub = jax.random.split(self.key)
-        nxt = sample(logits[:, -1], sub, self.sampler)
-
-        done_now: List[Request] = []
-        for slot, req in enumerate(self.slots):
-            if req is None:
-                continue
-            tok = int(nxt[slot])
-            req.generated.append(tok)
-            req.pos += 1
-            if tok == req.eos_id or len(req.generated) >= req.max_new:
-                req.done = True
-                done_now.append(req)
-                self.slots[slot] = None
-                self.user_inflight[req.user] = False
-            else:
-                self.tokens = self.tokens.at[slot].set(tok)
-        if done_now:
-            self._teardown([r.slot for r in done_now])
-        self.finished.extend(done_now)
+            with telemetry.span("sched.map_pages"):
+                self._map_decode_pages()
+        with telemetry.span("sched.dispatch"):
+            positions = jnp.array(
+                [[s.pos if s is not None else 0] for s in self.slots],
+                jnp.int32)
+            logits, self.cache = self.engine.decode(
+                self.tokens[:, None], positions, self.cache)
+            self.key, sub = jax.random.split(self.key)
+            nxt = sample(logits[:, -1], sub, self.sampler)
+        with telemetry.span("sched.wait", cpu=True):
+            # the host waits here for the device's step and reads every
+            # slot's token in one transfer
+            nxt = np.asarray(nxt)
+        with telemetry.span("sched.update"):
+            done_now: List[Request] = []
+            for slot, req in enumerate(self.slots):
+                if req is None:
+                    continue
+                tok = int(nxt[slot])
+                req.generated.append(tok)
+                req.pos += 1
+                if tok == req.eos_id or len(req.generated) >= req.max_new:
+                    req.done = True
+                    done_now.append(req)
+                    self.slots[slot] = None
+                    self.user_inflight[req.user] = False
+                else:
+                    self.tokens = self.tokens.at[slot].set(tok)
+            telemetry.count("sched.tokens", len(live))
+            if done_now:
+                self._teardown([r.slot for r in done_now])
+            self.finished.extend(done_now)
         return done_now
 
     def _spec_step(self) -> List[Request]:
@@ -601,7 +630,8 @@ class Scheduler:
         items = [(slot, req, self._host_prompt[slot] + req.generated)
                  for slot, req in enumerate(self.slots) if req is not None]
         props = self.draft.propose(items, K)            # (n_slots, K)
-        self._map_decode_pages(horizon=K + 1)
+        with telemetry.span("sched.map_pages"):
+            self._map_decode_pages(horizon=K + 1)
         toks = np.zeros((self.n_slots, K + 1), np.int32)
         base = np.zeros(self.n_slots, np.int32)
         for slot, req, hist in items:
@@ -614,7 +644,9 @@ class Scheduler:
             jnp.arange(K + 1, dtype=jnp.int32)[None]
         logits, cache = self.engine.decode(jnp.asarray(toks), positions,
                                            self.cache)
-        nxt = np.asarray(jnp.argmax(logits, -1).astype(jnp.int32))
+        nxt = jnp.argmax(logits, -1).astype(jnp.int32)
+        with telemetry.span("sched.wait", cpu=True):
+            nxt = np.asarray(nxt)
         self.spec_stats["verify_time"] += time.monotonic() - t0
         self.spec_stats["rounds"] += 1
         done_now: List[Request] = []
@@ -636,6 +668,7 @@ class Scheduler:
             self.spec_stats["proposed"] += K
             self.spec_stats["accepted"] += a
             self.spec_stats["emitted"] += emitted
+            telemetry.count("sched.tokens", emitted)
             if req.done:
                 done_now.append(req)
                 self.slots[slot] = None

@@ -154,7 +154,10 @@ def test_adapter_generate_batch_spec_wiring(engine, small_engine):
     adapter = ModelAdapter(ModelPool())
     prompts = ["hello world", "the quick brown fox", "prompt-centric"]
     plain = adapter.generate_batch([(mk(None), p, None) for p in prompts])
-    assert adapter.serving_stats == {}
+    # a plain decode leaves no speculation totals, only the scheduler's
+    s = adapter.serving_stats["qwen2-1.5b"]
+    assert "rounds" not in s and "enabled" not in s
+    assert s["schedulers"] == 1 and s["prefill_tokens"] > 0
     spec = adapter.generate_batch([(mk(small_engine), p, None)
                                    for p in prompts])
     assert spec == plain
