@@ -75,6 +75,8 @@ class Engine:
         return init_paged_cache(self.cfg, batch, n_pages, page_size, max_pages)
 
     def decode(self, tokens: jax.Array, positions: jax.Array, cache: Dict):
+        """One call of ``jit_decode_step``: ``(logits (B, S, V), cache)``.
+        A greedy caller picks its tokens with ``greedy(logits)``."""
         with telemetry.span("engine.decode"):
             return self._decode(self.params, tokens, positions, cache)
 
@@ -197,6 +199,16 @@ def decode_step(params: Dict, tokens: jax.Array, positions: jax.Array,
     return logits, new_cache
 
 
+@jax.jit
+def greedy(logits: jax.Array) -> jax.Array:
+    """Each position's greedy token, (B, S, V) -> (B, S) int32, under the
+    named scope ``sampler``: the scheduler's steps and the draft pick their
+    greedy tokens from the decode step's logits with this one compiled
+    program, not with eager ops."""
+    with jax.named_scope("sampler"):
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
 def serve_step(params: Dict, tokens: jax.Array, positions: jax.Array,
                cache: Dict, *, cfg: ModelConfig) -> Tuple[jax.Array, Dict]:
     """Dry-run artifact for decode shapes: one token, deep KV cache."""
@@ -261,7 +273,8 @@ class DraftEngine:
              widths: np.ndarray) -> jax.Array:
         """Feed ``toks_np[b, :widths[b]]`` at positions ``base[b]..`` for
         every slot in one call; pad rows write junk above each cursor (dead)
-        and their logits are ignored.  Returns logits (B, W, V)."""
+        and their tokens are ignored.  Returns each position's greedy token
+        (B, W) int32."""
         Ln = self.cache["kv"]["pos"].shape[0]
         B, W = toks_np.shape
         base_dev = jnp.asarray(base, jnp.int32)
@@ -272,7 +285,7 @@ class DraftEngine:
         # per-slot cursor rewind: the uniform-width call advanced every row
         # by W; the true fed-prefix grew by each slot's REAL width
         self.dpos = base + widths
-        return logits
+        return greedy(logits)
 
     def propose(self, items, k: int) -> np.ndarray:
         """items: list of (slot, req, hist) for live slots, ``hist`` the full
@@ -292,17 +305,15 @@ class DraftEngine:
         toks = np.zeros((self.n_slots, W), np.int32)
         for slot, _req, hist in items:
             toks[slot, :widths[slot]] = hist[int(base[slot]):]
-        logits = self._run(toks, base, widths)
+        toks_out = self._run(toks, base, widths)
         rows = jnp.asarray(np.maximum(widths - 1, 0), jnp.int32)
-        cur = jnp.argmax(
-            logits[jnp.arange(self.n_slots), rows], -1).astype(jnp.int32)
+        cur = toks_out[jnp.arange(self.n_slots), rows]
         props = [cur]
         live = widths > 0
         for i in range(1, k):
             step_base = np.where(live, hlen + i - 1, self.dpos)
-            logits = self._run(np.asarray(cur)[:, None].astype(np.int32),
-                               step_base, live.astype(np.int64))
-            cur = jnp.argmax(logits[:, 0], -1).astype(jnp.int32)
+            cur = self._run(np.asarray(cur)[:, None].astype(np.int32),
+                            step_base, live.astype(np.int64))[:, 0]
             props.append(cur)
         self._hist_at_propose[slots] = hlen[slots]
         out = np.asarray(jnp.stack(props, axis=1))            # (n_slots, k)

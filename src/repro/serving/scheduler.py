@@ -56,7 +56,7 @@ import numpy as np
 from repro import configs
 from repro.core import telemetry
 from repro.serving import discipline, kv_cache
-from repro.serving.engine import DraftEngine, Engine
+from repro.serving.engine import DraftEngine, Engine, greedy
 from repro.serving.sampler import SamplerConfig, sample
 
 
@@ -82,6 +82,23 @@ class Request:
     slot: int = -1
     pos: int = 0
     done: bool = False
+
+
+@jax.jit
+def page_table_update(table: jax.Array, pos: jax.Array, rows: jax.Array,
+                      reset: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """The paged cache's table and cursors after one fixed-shape patch.
+
+    table: (L, n_slots, max_pages); pos: (L, n_slots); rows: (n_slots, 3)
+    int32 of (slot, logical page, physical page), padded with slot ==
+    n_slots (out of bounds, so dropped); reset: (n_slots,) bool, whose
+    slots get an empty table row (-1) and cursor 0 before the rows land.
+    Its shapes depend on the scheduler alone, so it compiles once."""
+    table = jnp.where(reset[None, :, None], -1, table)
+    pos = jnp.where(reset[None, :], 0, pos)
+    table = table.at[:, rows[:, 0], rows[:, 1]].set(rows[None, :, 2],
+                                                    mode="drop")
+    return table, pos
 
 
 def _pow2_bucket(n: int, lo: int = 16) -> int:
@@ -136,7 +153,9 @@ class Scheduler:
             # (pad KV is dead under the causal mask once the cursor is rewound);
             # recurrent caches have no cursor and batch equal lengths only
             self._pad_ok = set(self.cache.keys()) <= {"kv"}
-        self.tokens = jnp.zeros((n_slots,), jnp.int32)
+        # every slot's next input token, (n_slots, 1): the step's greedy
+        # output, fed straight back (admission writes a slot's first token)
+        self.tokens = jnp.zeros((n_slots, 1), jnp.int32)
         self.key = jax.random.PRNGKey(seed)
         self.finished: List[Request] = []
         self._rr_start = 0                # round-robin start index over users
@@ -311,7 +330,8 @@ class Scheduler:
         firsts = jnp.argmax(
             logits[jnp.arange(len(reqs)), lens_arr - 1], axis=-1
         ).astype(jnp.int32)
-        self.tokens = self.tokens.at[jnp.asarray(slots, jnp.int32)].set(firsts)
+        self.tokens = self.tokens.at[jnp.asarray(slots, jnp.int32), 0].set(
+            firsts)
         for slot, req, l, first in zip(slots, reqs, lens,
                                        np.asarray(firsts).tolist()):
             req.slot = slot
@@ -506,7 +526,8 @@ class Scheduler:
         last = jnp.asarray([l - 1 - st for l, st in zip(lens, starts)], jnp.int32)
         firsts = jnp.argmax(
             logits[jnp.arange(B), last], axis=-1).astype(jnp.int32)
-        self.tokens = self.tokens.at[jnp.asarray(slots, jnp.int32)].set(firsts)
+        self.tokens = self.tokens.at[jnp.asarray(slots, jnp.int32), 0].set(
+            firsts)
         for (slot, req, tokens, _sh, _st, _cw), first in zip(
                 plan, np.asarray(firsts).tolist()):
             req.slot = slot
@@ -530,8 +551,9 @@ class Scheduler:
         slot's remaining decode budget so mapping never outruns the admission
         reservation — positions past the budget are routed to the trash page
         by the model's scatter and their rows are never emitted.  Pages come
-        out of the reservation, so allocation can't fail; the device table
-        is patched with ONE scatter."""
+        out of the reservation, so allocation can't fail.  The host tables
+        (``_tables``) decide; the device table follows in one call of
+        ``page_table_update`` per ``n_slots`` new pages."""
         upd: List[Tuple[int, int, int]] = []       # (slot, logical, physical)
         for slot, req in enumerate(self.slots):
             if req is None:
@@ -547,21 +569,35 @@ class Scheduler:
                     self._tables[slot, pi] = page
                     upd.append((slot, pi, page))
         if upd:
-            paged = self.cache["paged"]
-            s = jnp.asarray([u[0] for u in upd], jnp.int32)
-            li = jnp.asarray([u[1] for u in upd], jnp.int32)
-            pg = jnp.asarray([u[2] for u in upd], jnp.int32)
-            table = paged["table"].at[:, s, li].set(pg[None])
-            self.cache = {"paged": {**paged, "table": table}}
+            self._patch_tables(upd, [])
+
+    def _patch_tables(self, upd: List[Tuple[int, int, int]],
+                      reset: List[int]) -> None:
+        """Map ``upd`` (slot, logical, physical) pages into the device page
+        tables, or clear the ``reset`` slots' tables and cursors, with the
+        fixed-shape ``page_table_update`` program: one dispatch per
+        ``n_slots`` rows, padded, so it never compiles again."""
+        n = self.n_slots
+        rows = np.full((-(-max(len(upd), 1) // n) * n, 3), n, np.int32)
+        rows[:len(upd)] = np.asarray(upd, np.int32).reshape(-1, 3)
+        mask = np.zeros(n, bool)
+        mask[list(reset)] = True
+        paged = self.cache["paged"]
+        table, pos = paged["table"], paged["pos"]
+        for chunk in rows.reshape(-1, n, 3):
+            table, pos = page_table_update(table, pos, chunk, mask)
+        self.cache = {"paged": {**paged, "table": table, "pos": pos}}
 
     # -- one decode step over the whole batch --------------------------------
     def step(self) -> List[Request]:
         """Admit, then decode one token for every live slot.  The step's
         phases are telemetry spans: ``sched.admit`` (prefill inside),
-        ``sched.map_pages``, ``sched.dispatch`` (the decode and the sampler
-        enqueued), ``sched.wait`` (the host waits for the device and reads
-        the tokens) and ``sched.update`` (the tokens' bookkeeping and
-        teardown)."""
+        ``sched.map_pages`` (the page-table patch on a step that crosses a
+        page), ``sched.dispatch`` (the one ``Engine.decode`` call and the
+        compiled ``greedy`` pick; a sampler with a temperature draws with a
+        key split instead), ``sched.wait`` (the host waits for the
+        device and reads the tokens) and ``sched.update`` (the tokens'
+        bookkeeping and teardown)."""
         with telemetry.span("sched.step"):
             return self._step()
 
@@ -580,17 +616,22 @@ class Scheduler:
             with telemetry.span("sched.map_pages"):
                 self._map_decode_pages()
         with telemetry.span("sched.dispatch"):
-            positions = jnp.array(
+            # a fresh host array each step: the call uploads it, and nothing
+            # mutates it afterwards
+            positions = np.array(
                 [[s.pos if s is not None else 0] for s in self.slots],
-                jnp.int32)
+                np.int32)
             logits, self.cache = self.engine.decode(
-                self.tokens[:, None], positions, self.cache)
-            self.key, sub = jax.random.split(self.key)
-            nxt = sample(logits[:, -1], sub, self.sampler)
+                self.tokens, positions, self.cache)
+            if self.sampler.temperature > 0:
+                self.key, sub = jax.random.split(self.key)
+                self.tokens = sample(logits[:, -1], sub, self.sampler)[:, None]
+            else:
+                self.tokens = greedy(logits)
         with telemetry.span("sched.wait", cpu=True):
             # the host waits here for the device's step and reads every
             # slot's token in one transfer
-            nxt = np.asarray(nxt)
+            nxt = np.asarray(self.tokens)[:, 0]
         with telemetry.span("sched.update"):
             done_now: List[Request] = []
             for slot, req in enumerate(self.slots):
@@ -604,8 +645,6 @@ class Scheduler:
                     done_now.append(req)
                     self.slots[slot] = None
                     self.user_inflight[req.user] = False
-                else:
-                    self.tokens = self.tokens.at[slot].set(tok)
             telemetry.count("sched.tokens", len(live))
             if done_now:
                 self._teardown([r.slot for r in done_now])
@@ -644,7 +683,7 @@ class Scheduler:
             jnp.arange(K + 1, dtype=jnp.int32)[None]
         logits, cache = self.engine.decode(jnp.asarray(toks), positions,
                                            self.cache)
-        nxt = jnp.argmax(logits, -1).astype(jnp.int32)
+        nxt = greedy(logits)
         with telemetry.span("sched.wait", cpu=True):
             nxt = np.asarray(nxt)
         self.spec_stats["verify_time"] += time.monotonic() - t0
@@ -755,8 +794,9 @@ class Scheduler:
 
     def _teardown(self, slots: List[int]) -> None:
         """Batched end-of-step teardown: ONE masked pass (dense) or ONE
-        table/cursor reset (paged) for every slot finished this step, plus
-        page refcount release on the pool."""
+        ``page_table_update`` call resetting tables and cursors (paged) for
+        every slot finished this step, plus page refcount release on the
+        pool."""
         if not self.paged:
             self.cache = kv_cache.reset_slots(self.cache, slots)
             return
@@ -768,13 +808,7 @@ class Scheduler:
             self._tables[slot] = -1
             self._slot_unreserved[slot] = 0
             self._host_prompt.pop(slot, None)
-        paged = self.cache["paged"]
-        sl = jnp.asarray(slots, jnp.int32)
-        self.cache = {"paged": {
-            **paged,
-            "table": paged["table"].at[:, sl, :].set(-1),
-            "pos": paged["pos"].at[:, sl].set(0),
-        }}
+        self._patch_tables([], slots)
 
     def run_to_completion(self, max_steps: int = 10_000) -> List[Request]:
         for _ in range(max_steps):

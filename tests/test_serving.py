@@ -473,3 +473,130 @@ def test_sampler_greedy_and_topk():
     sc = SamplerConfig(temperature=1.0, top_k=2)
     draws = {int(sample(logits, jax.random.PRNGKey(i), sc)[0]) for i in range(40)}
     assert draws <= {1, 2}, "top-k truncation leaked"
+
+
+# --------------------------------------------------------------------------
+# the plain decode step's host side: one decode call, no eager glue
+# --------------------------------------------------------------------------
+@pytest.mark.parametrize("paged,n_slots", [(False, 1), (False, 4),
+                                           (True, 1), (True, 4)])
+def test_greedy_scheduler_bit_identical_to_generate(engine, paged, n_slots):
+    """The step feeds the compiled greedy pick straight back as the next
+    tokens: every answer equals ``Engine.generate``'s, with slots finishing
+    at different steps and being re-admitted, and (paged, 8-token pages)
+    decodes crossing two and three page boundaries."""
+    rng = np.random.default_rng(11)
+    lens, max_new = (5, 9, 3, 12, 7, 6), (20, 4, 13, 17, 9, 21)
+    prompts = [jnp.asarray(rng.integers(3, 90, l), jnp.int32) for l in lens]
+    kw = dict(paged=True, page_size=8) if paged else {}
+    sch = Scheduler(engine, n_slots=n_slots, **kw)
+    for i, (p, m) in enumerate(zip(prompts, max_new)):
+        sch.submit(Request(rid=i, user=f"g{i}", prompt=p, max_new=m))
+    done = sch.run_to_completion()
+    assert len(done) == 6
+    for r in done:
+        ref = engine.generate(prompts[r.rid][None, :], max_new=max_new[r.rid])
+        assert r.generated == [int(t) for t in np.asarray(ref[0])]
+    if paged:
+        sch.pool.check()
+
+
+@pytest.mark.parametrize("paged", [False, True])
+def test_sampled_scheduler_matches_split_and_sample(engine, paged):
+    """With a temperature the step keeps the eager key split and draw: the
+    tokens equal ``split`` + ``sample(logits[:, -1])`` run by hand from the
+    same seed."""
+    sc = SamplerConfig(temperature=0.8, top_k=20)
+    prompt = jnp.arange(9, dtype=jnp.int32) + 3
+    kw = dict(paged=True, page_size=8) if paged else {}
+    sch = Scheduler(engine, n_slots=1, sampler=sc, seed=5, **kw)
+    sch.submit(Request(rid=0, user="t", prompt=prompt, max_new=12))
+    got = sch.run_to_completion()[0].generated
+
+    cache = engine.new_cache(1, 64)
+    logits, cache = engine.prefill(prompt[None, :], cache)
+    tok = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)
+    key, want = jax.random.PRNGKey(5), []
+    for i in range(12):
+        want.append(int(tok[0]))
+        key, sub = jax.random.split(key)
+        logits, cache = engine.decode(
+            tok[:, None], jnp.full((1, 1), 9 + i, jnp.int32), cache)
+        tok = sample(logits[:, -1], sub, sc)
+    assert got == want
+
+
+class _Recording:
+    """Stands in for a module in the scheduler's namespace and records every
+    name the scheduler reads from it."""
+
+    def __init__(self, module, seen):
+        self._module, self._seen = module, seen
+
+    def __getattr__(self, name):
+        self._seen.append(name)
+        return getattr(self._module, name)
+
+
+def test_steady_state_step_is_one_decode_call(engine, monkeypatch):
+    """A step with no admission, page crossing or teardown makes exactly one
+    ``Engine.decode`` call and no eager ``jnp``, ``jax.random`` or ``.at[]``
+    op: positions go up as a fresh host array, and the compiled greedy
+    pick of the previous step is the next step's tokens."""
+    from repro.serving import scheduler as sched_mod
+    sch = Scheduler(engine, n_slots=2, paged=True, page_size=16)
+    for i in range(2):
+        sch.submit(Request(rid=i, user=f"s{i}", max_new=12,
+                           prompt=jnp.arange(5 + i, dtype=jnp.int32) + 3))
+    sch.step()                                   # admits both, first decode
+    sch.step()
+    assert all(s is not None and s.pos % 16 for s in sch.slots)
+
+    calls, seen, at = [], [], []
+    decode = engine.decode
+
+    def counting_decode(*a):
+        calls.append(a)
+        return decode(*a)
+    monkeypatch.setattr(engine, "decode", counting_decode)
+    monkeypatch.setattr(sched_mod, "jnp", _Recording(jnp, seen))
+    monkeypatch.setattr(sched_mod, "jax", _Recording(jax, seen))
+    array_type = type(sch.tokens)
+    at_prop = array_type.at
+    monkeypatch.setattr(array_type, "at", property(
+        lambda x: at.append(1) or at_prop.fget(x)))
+    want = [[s.pos] for s in sch.slots]
+    sch.step()
+    assert len(calls) == 1 and calls[0][0].shape == (2, 1)
+    np.testing.assert_array_equal(np.asarray(calls[0][1]), want)
+    assert seen == [] and at == []
+
+
+def test_page_crossings_and_teardown_patch_the_device_tables(engine,
+                                                             monkeypatch):
+    """The device page tables follow the host tables through every lazy
+    mapping and teardown, each a single call of the fixed-shape patch
+    program (which compiles once for the scheduler's shapes)."""
+    from repro.serving import scheduler as sched_mod
+    patches = []
+    update = sched_mod.page_table_update
+
+    def counting_update(*a):
+        patches.append(a[2].shape)
+        return update(*a)
+    monkeypatch.setattr(sched_mod, "page_table_update", counting_update)
+    sch = Scheduler(engine, n_slots=3, paged=True, page_size=4)
+    for i in range(5):
+        sch.submit(Request(rid=i, user=f"p{i}", max_new=6 + 3 * i,
+                           prompt=jnp.arange(3 + i, dtype=jnp.int32) + 3))
+    while sch.pending():
+        n0 = len(patches)
+        done = sch.step()
+        assert len(patches) - n0 <= 1 + int(bool(done))  # map + teardown
+        table = np.asarray(sch.cache["paged"]["table"])
+        assert (table == sch._tables[None]).all()
+        for r in done:
+            assert (np.asarray(sch.cache["paged"]["pos"])[:, r.slot] == 0).all()
+    assert len(sch.finished) == 5 and patches
+    assert set(patches) == {(3, 3)}
+    sch.pool.check()
