@@ -2,8 +2,9 @@
 references that import nothing of the program.
 
 * Engine answers: a sample drawn from the seed of the window's engine-decoded
-  requests, the longest among them, is run through the float32 reference
-  (``bench/model.py``) over each prompt the engine saw and the tokens it
+  requests, the longest among them, is run through the float32 reference,
+  the ``logits`` of the configuration's family (``bench/families/``, by way
+  of ``bench/model.py``), over each prompt the engine saw and the tokens it
   served.  ``logit_gap`` is the widest gap by which a served token's
   reference logit lies below the reference's best: greedy decoding in the
   served bfloat16 only loses near-ties, a wrong page, mask, kernel or
@@ -29,6 +30,8 @@ import hashlib
 from typing import Any, Dict, List, Tuple
 
 import numpy as np
+
+from bench import common
 
 N_SPECIAL = 3          # pad, bos, eos come before the 256 byte ids
 BOS = 1
@@ -147,12 +150,13 @@ def cache_wrong(records, requests_by_i, objects, dim: int,
     return wrong, len(hits)
 
 
-def logit_gap(params, config, rows, fp8: bool = False) -> float:
+def logit_gap(params, config, rows, fp8: bool = False,
+              bench_dir: str = common.BENCH_DIR) -> float:
     from bench import model
     if not rows:
         return 0.0
     gaps = np.concatenate([model.token_gaps(params, rows[i:i + 4], config,
-                                            fp8=fp8)
+                                            fp8=fp8, bench_dir=bench_dir)
                            for i in range(0, len(rows), 4)])
     return float(gaps.max())
 

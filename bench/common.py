@@ -1,8 +1,10 @@
-"""File lookup by name: configurations, traffic mixes, cells, metric readers,
-kernel counts and the table of peaks all live in files of their own under
-``bench/``, so a new cell, mix, configuration or metric is added as files."""
+"""File lookup by name: configurations, model families, traffic mixes, cells,
+metric readers, kernel counts and the table of peaks all live in files of
+their own under ``bench/``, so a new cell, mix, configuration, architecture or
+metric is added as files."""
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 import os
@@ -48,6 +50,34 @@ def load_reader(kind: str, name: str, bench_dir: str = BENCH_DIR):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+@functools.lru_cache(maxsize=None)
+def _family(name: str, bench_dir: str):
+    return load_reader("families", name, bench_dir)
+
+
+def load_family(c: Dict[str, Any], bench_dir: str = BENCH_DIR):
+    """The model family that configuration ``c`` names under ``"family"``:
+    the module ``bench/families/<family>.py``, loaded once per file.  It
+    gives the harness ``param_shapes(c)`` (the served weights' layout),
+    ``logits(params, tokens, c, fp8=False)`` (the float32 reference and its
+    float8 control), ``program_config(c)`` (the program's ``ModelConfig``),
+    ``decode_weights(c)`` and ``decode_cost(c, pos)`` (one decode step's
+    counts) and ``attention_layers(c)`` (the layers that call the paged
+    decode kernel once a step).  There is no default family: a
+    configuration without the key, or naming no file, is an error that
+    names the path."""
+    where = os.path.join(bench_dir, "families")
+    if "family" not in c:
+        raise KeyError(f"configuration {c.get('arch')!r} names no family: "
+                       f"it needs a \"family\" key naming a file "
+                       f"{where}/<family>.py")
+    path = reader_path("families", c["family"], bench_dir)
+    if not os.path.exists(path):
+        raise FileNotFoundError(f"configuration {c.get('arch')!r} names "
+                                f"family {c['family']!r}: no file {path}")
+    return _family(c["family"], os.path.abspath(bench_dir))
 
 
 def load_benchmark(root: str = ROOT) -> Dict[str, Any]:

@@ -48,18 +48,10 @@ def load_cell(name: str, bench_dir: str = common.BENCH_DIR) -> Cell:
                 bench_dir=bench_dir)
 
 
-def program_config(c: Dict[str, Any]):
-    """The program's ``ModelConfig`` for the configuration file: the arch's
-    own config with every size taken from the file, kernels on."""
-    from repro import configs
-    cfg = configs.get(c["arch"], dtype=c["dtype"])
-    return dataclasses.replace(
-        cfg, n_layers=c["num_hidden_layers"], d_model=c["hidden_size"],
-        n_heads=c["num_attention_heads"], n_kv_heads=c["num_key_value_heads"],
-        head_dim=c["head_dim"], d_ff=c["intermediate_size"],
-        vocab=c["vocab_size"], norm_eps=c["rms_norm_eps"],
-        rope_theta=c["rope_theta"], qkv_bias=c["attention_bias"],
-        tie_embeddings=c["tie_word_embeddings"], use_pallas=True)
+def program_config(c: Dict[str, Any], bench_dir: str = common.BENCH_DIR):
+    """The program's ``ModelConfig`` for the configuration file, as its
+    family (``bench/families/<family>.py``) maps it."""
+    return common.load_family(c, bench_dir).program_config(c)
 
 
 # -- wrappers installed from outside --------------------------------------------
@@ -194,9 +186,9 @@ class Server:
         from repro.models import init_model
         from repro.serving.engine import Engine
 
-        cfg = program_config(cell.config)
+        cfg = program_config(cell.config, cell.bench_dir)
         t = time.monotonic()
-        self.params = bmodel.make_weights(cell.config, seed)
+        self.params = bmodel.make_weights(cell.config, seed, cell.bench_dir)
         jax.block_until_ready(self.params)
         self.t_weights = time.monotonic() - t
         # the program's layout, read from its own init without running it
@@ -393,6 +385,10 @@ class Context:
 
     def kernel(self, name: str):
         return common.load_reader("kernels", name, self.cell.bench_dir)
+
+    def family(self):
+        """The configuration's model family (``bench/families/``)."""
+        return common.load_family(self.config, self.cell.bench_dir)
 
     def kernel_ops(self, k):
         """(start, end) of the traced window's calls of kernel module ``k``

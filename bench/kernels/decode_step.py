@@ -1,24 +1,21 @@
 """Model operations and bytes of one decode step of one token at position
-``pos`` through the whole dense GQA decoder: every projection and the tied
+``pos`` through the whole model: ``weights(c)``, the weight elements a step
+reads, and ``cost(c, pos)``, its (operations, bytes).  Both are the
+configuration's family's (``bench/families/<family>.py``, ``decode_weights``
+and ``decode_cost``); for the dense family, every projection and the tied
 vocabulary projection (two operations per weight), plus attention over the
-``pos + 1`` cached positions in every layer.  Bytes: every weight once in
-bfloat16 and the live keys and values of every layer."""
+``pos + 1`` cached positions in every layer, and in bytes every weight once
+in bfloat16 and the live keys and values of every layer."""
+import os
 
-BYTES_PER_ELEM = 2
+from bench import common
+
+BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def weights(c) -> int:
-    d, ff, V = c["hidden_size"], c["intermediate_size"], c["vocab_size"]
-    hd = c["head_dim"]
-    q, kv = c["num_attention_heads"] * hd, c["num_key_value_heads"] * hd
-    layer = d * q + 2 * d * kv + q * d + 3 * d * ff
-    return c["num_hidden_layers"] * layer + V * d
+    return common.load_family(c, BENCH_DIR).decode_weights(c)
 
 
 def cost(c, pos: int):
-    L, hd = c["num_hidden_layers"], c["head_dim"]
-    hq, hk = c["num_attention_heads"], c["num_key_value_heads"]
-    live = pos + 1
-    flops = 2 * weights(c) + L * 4 * hq * hd * live
-    nbytes = BYTES_PER_ELEM * (weights(c) + L * 2 * hk * hd * live)
-    return float(flops), float(nbytes)
+    return common.load_family(c, BENCH_DIR).decode_cost(c, pos)

@@ -1,11 +1,13 @@
-"""Weights from the seed, and the plain reference of the served models.
+"""Weights from the seed, and the comparison of served tokens with the
+plain reference of the served model.
 
-The benchmark makes the weights itself, in the program's parameter layout
-and in the type they are served in, on the device in one jitted call from
-``--seed``; it hands them to the program's ``Engine``.  The reference below
-imports nothing of the program: it is the dense GQA decoder of the
-configuration file written out in ``jax.numpy`` at float32 with matmuls at
-``HIGHEST`` precision, no kernels, no cache and no batching of its own.  It
+The benchmark makes the weights itself, in the layout that the
+configuration's family (``bench/families/<family>.py``) gives and in the
+type they are served in, on the device in one jitted call from ``--seed``;
+it hands them to the program's ``Engine``.  The reference is the family's
+``logits``, which imports nothing of the program: the architecture written
+out in ``jax.numpy`` at float32 with matmuls at ``HIGHEST`` precision, no
+kernels, no cache and no batching of its own, from the helpers below.  It
 runs after the measured window, one scan over the layers, so that it never
 holds more than the served weights and one layer in float32.
 
@@ -17,34 +19,25 @@ might be tempted to serve in.
 from __future__ import annotations
 
 import functools
+import json
 from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench import common
+
 HI = jax.lax.Precision.HIGHEST
 F8 = jnp.float8_e4m3fn
 F8_MAX = 448.0
 
 
-def _shapes(c: Dict[str, Any]) -> Dict[str, Any]:
-    d, L, V = c["hidden_size"], c["num_hidden_layers"], c["vocab_size"]
-    hd = c["head_dim"]
-    q, kv, ff = c["num_attention_heads"] * hd, c["num_key_value_heads"] * hd, \
-        c["intermediate_size"]
-    layers = {"wq": (L, d, q), "wk": (L, d, kv), "wv": (L, d, kv),
-              "wo": (L, q, d), "attn_norm_w": (L, d),
-              "w_gate": (L, d, ff), "w_up": (L, d, ff), "w_down": (L, ff, d),
-              "mlp_norm_w": (L, d)}
-    if c["attention_bias"]:
-        layers.update(bq=(L, q), bk=(L, kv), bv=(L, kv))
-    return {"embed": (V, d), "final_norm_w": (d,), "layers": layers}
-
-
-def param_shapes(c: Dict[str, Any]) -> Dict[str, Any]:
-    """Shape tree of the served weights (the program's layout)."""
-    return _shapes(c)
+def param_shapes(c: Dict[str, Any],
+                 bench_dir: str = common.BENCH_DIR) -> Dict[str, Any]:
+    """Shape tree of the served weights (the program's layout), as the
+    configuration's family gives it."""
+    return common.load_family(c, bench_dir).param_shapes(c)
 
 
 def _draw(key, path: str, shape, dtype):
@@ -60,10 +53,11 @@ def _draw(key, path: str, shape, dtype):
     return x.astype(dtype)
 
 
-def make_weights(c: Dict[str, Any], seed: int):
+def make_weights(c: Dict[str, Any], seed: int,
+                 bench_dir: str = common.BENCH_DIR):
     """All weights in ``c["dtype"]``, drawn on the device in ONE jitted call.
     ``seed`` may exceed 32 bits; it is folded into the key."""
-    shapes = _shapes(c)
+    shapes = param_shapes(c, bench_dir)
     leaves = []
 
     def walk(tree, prefix):
@@ -128,53 +122,10 @@ def _rope(x, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def logits(params, tokens, c: Dict[str, Any], fp8: bool = False):
-    """(B, S, V) float32 logits of ``tokens`` (B, S) from position 0."""
-    f32 = lambda a: a.astype(jnp.float32)
-    H, Hk, hd = (c["num_attention_heads"], c["num_key_value_heads"],
-                 c["head_dim"])
-    eps, theta = c["rms_norm_eps"], c["rope_theta"]
-    # Granite's scalar multipliers; 1 (and 1/sqrt(head_dim)) where a
-    # configuration has none
-    em = c.get("embedding_multiplier", 1.0)
-    am = c.get("attention_multiplier", 1.0 / np.sqrt(hd))
-    rm = c.get("residual_multiplier", 1.0)
-    ls = c.get("logits_scaling", 1.0)
-    B, S = tokens.shape
-    emb = f32(params["embed"])
-    if fp8:
-        emb = _q8(emb, 1)
-    x = emb[tokens] * em
-    causal = jnp.tril(jnp.ones((S, S), bool))
-
-    def layer(x, p):
-        p = jax.tree.map(f32, p)
-        h = _rms(x, p["attn_norm_w"], eps)
-        q, k, v = (_mm(h, p[w], fp8) for w in ("wq", "wk", "wv"))
-        if c["attention_bias"]:
-            q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
-        q = _rope(q.reshape(B, S, H, hd), theta)
-        k = _rope(k.reshape(B, S, Hk, hd), theta)
-        v = v.reshape(B, S, Hk, hd)
-        q = q.reshape(B, S, Hk, H // Hk, hd)
-        s = jnp.einsum("bqkgd,bskd->bkgqs", q, k, precision=HI) * am
-        s = jnp.where(causal, s, -jnp.inf)
-        a = jnp.einsum("bkgqs,bskd->bqkgd", jax.nn.softmax(s, -1), v,
-                       precision=HI).reshape(B, S, H * hd)
-        x = x + rm * _mm(a, p["wo"], fp8)
-        h = _rms(x, p["mlp_norm_w"], eps)
-        g = jax.nn.silu(_mm(h, p["w_gate"], fp8)) * _mm(h, p["w_up"], fp8)
-        return x + rm * _mm(g, p["w_down"], fp8), None
-
-    x, _ = jax.lax.scan(layer, x, params["layers"])
-    x = _rms(x, f32(params["final_norm_w"]), eps)
-    return jnp.einsum("bsd,vd->bsv", x if not fp8 else _q8(x, -1), emb,
-                      precision=HI) / ls
-
-
-@functools.partial(jax.jit, static_argnames=("cfg_items", "fp8"))
-def _gaps(params, tokens, served, mask, cfg_items, fp8):
-    c = dict(cfg_items)
+@functools.partial(jax.jit, static_argnames=("cfg_json", "fp8", "bench_dir"))
+def _gaps(params, tokens, served, mask, cfg_json, fp8, bench_dir):
+    c = json.loads(cfg_json)
+    logits = common.load_family(c, bench_dir).logits
     ref = logits(params, tokens, c)
     best = ref.max(-1)
     if fp8:
@@ -186,7 +137,8 @@ def _gaps(params, tokens, served, mask, cfg_items, fp8):
 
 
 def token_gaps(params, rows, c: Dict[str, Any], fp8: bool = False,
-               width: int = 256) -> np.ndarray:
+               width: int = 256,
+               bench_dir: str = common.BENCH_DIR) -> np.ndarray:
     """The gap, in reference logits, by which each served token (or, with
     ``fp8``, the control's first choice) lies below the reference's best.
 
@@ -206,7 +158,6 @@ def token_gaps(params, rows, c: Dict[str, Any], fp8: bool = False,
         lo = len(p) - 1
         served[b, lo:lo + len(s)] = s
         mask[b, lo:lo + len(s)] = True
-    items = tuple(sorted((k, v) for k, v in c.items()
-                         if isinstance(v, (int, float, str, bool))))
     return np.asarray(_gaps(params, jnp.asarray(tokens), jnp.asarray(served),
-                            jnp.asarray(mask), items, fp8))
+                            jnp.asarray(mask), json.dumps(c, sort_keys=True),
+                            fp8, bench_dir))

@@ -214,10 +214,11 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
     picked = check.sample(records, served, seed, spec["sample_requests"])
     rows, unmatched = check.engine_rows(picked, by_i, served, index,
                                         cell.config["vocab_size"])
-    gap = check.logit_gap(params, cell.config, rows)
+    gap = check.logit_gap(params, cell.config, rows, bench_dir=bench_dir)
     if control:
         out["program"] = {"logit_gap": gap}
-        gap = check.logit_gap(params, cell.config, rows, fp8=True)
+        gap = check.logit_gap(params, cell.config, rows, fp8=True,
+                              bench_dir=bench_dir)
     wrong, n_cache = check.cache_wrong(records, by_i, objects,
                                        ctx.cache_dim, spec["cache_relevance"],
                                        model_name)

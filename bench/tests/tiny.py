@@ -1,5 +1,6 @@
 """A throwaway benchmark tree for the harness's own tests: the repository's
-``BENCHMARK.json`` and ``bench/`` data copied into a temporary directory,
+``BENCHMARK.json`` and ``bench/`` data, model families, metric readers and
+kernel counts copied into a temporary directory,
 plus a tiny configuration, two tiny mixes and their cells, added as files
 only.  The tiny streamed cell reports what the repository's streamed cell
 lists; the whole-answer cell, a kind the repository does not have yet,
@@ -14,7 +15,8 @@ import shutil
 from bench import common
 
 TINY_CONFIG = {
-    "arch": "qwen2-1.5b", "source": "test", "hidden_act": "silu",
+    "arch": "qwen2-1.5b", "family": "dense", "source": "test",
+    "hidden_act": "silu",
     "hidden_size": 64, "intermediate_size": 128, "num_hidden_layers": 2,
     "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
     "vocab_size": 512, "rms_norm_eps": 1e-06, "rope_theta": 10000.0,
@@ -47,7 +49,8 @@ def make_tree(tmp: str) -> str:
     """Copy the benchmark's data into ``tmp`` and add the tiny cells.
     Returns the new ``bench`` directory."""
     bench_dir = os.path.join(tmp, "bench")
-    for kind in ("configs", "traffic", "workloads", "metrics", "kernels"):
+    for kind in ("configs", "families", "traffic", "workloads", "metrics",
+                 "kernels"):
         shutil.copytree(os.path.join(common.BENCH_DIR, kind),
                         os.path.join(bench_dir, kind))
     shutil.copy(os.path.join(common.BENCH_DIR, "peaks.json"), bench_dir)
